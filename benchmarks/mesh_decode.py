@@ -38,7 +38,7 @@ from repro.launch.steps import abstract_params
 from repro.models import lm as M
 from repro.models.param import unzip
 from repro.parallel.rules import rules_for
-from repro.parallel.sharding import make_mesh_compat
+from repro.parallel.sharding import auto_mesh
 from repro.serving.engine import ServeEngine
 
 LEDGER_ROUNDING = 0.05
@@ -83,7 +83,7 @@ def _standalone_shard_ledger(
 def run(quick: bool = False) -> dict:
     n_dev = jax.device_count()
     mesh_shape = (2, n_dev // 2) if n_dev >= 4 else (1, n_dev)
-    mesh = make_mesh_compat(mesh_shape, ("data", "model"))
+    mesh = auto_mesh(mesh_shape, ("data", "model"))
     cfg = dataclasses.replace(get_smoke_config("qwen2-1.5b"), dtype="float32")
     params, _ = unzip(M.init_lm(cfg, jax.random.key(0)))
     failures: list[str] = []

@@ -19,7 +19,7 @@ from repro.launch.steps import build_train_step
 from repro.models import lm as M
 from repro.models.param import unzip
 from repro.parallel.rules import rules_for
-from repro.parallel.sharding import make_mesh_compat, set_mesh_compat
+from repro.parallel.sharding import auto_mesh
 from repro.train.optimizer import adamw, cosine_schedule
 
 
@@ -43,7 +43,7 @@ def main():
     print(f"[lm_train] {cfg.name}: {cfg.param_count()/1e6:.1f}M params")
     params, _ = unzip(M.init_lm(cfg, jax.random.key(0)))
 
-    mesh = make_mesh_compat((jax.device_count(), 1), ("data", "model"))
+    mesh = auto_mesh((jax.device_count(), 1), ("data", "model"))
     rules = rules_for(cfg, "train", mesh)
     opt = adamw(cosine_schedule(3e-4, args.steps, warmup_steps=20))
     opt_state = opt.init(params)
@@ -52,7 +52,7 @@ def main():
 
     data = token_batches(args.batch, args.seq, cfg.vocab, seed=7)
     t0, first_loss = time.time(), None
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         for i, (tok, lab) in enumerate(data):
             if i >= args.steps:
                 break

@@ -29,8 +29,7 @@ def _is_low_precision_float(dtype) -> bool:
 
 
 def _pallas_kernel_name(eqn) -> str:
-    info = eqn.params.get("name_and_src_info")
-    return getattr(info, "name", "") or str(info or "")
+    return str(eqn.params.get("name") or "")
 
 
 @rule("dtype/no-f64", needs=("jaxpr",))

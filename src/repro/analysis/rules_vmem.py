@@ -14,8 +14,8 @@ from repro.kernels import tuning
 
 def _block_specs(eqn) -> tuple[list, list, list] | None:
     """(in_blocks, out_blocks, scratch_blocks) of one pallas_call eqn, each a
-    list of ``(block_shape, dtype_bytes)`` — None when the eqn doesn't carry
-    the jax 0.4-style grid mapping (e.g. a synthetic test jaxpr)."""
+    list of ``(block_shape, dtype_bytes)`` — None when the eqn carries no
+    grid mapping (e.g. a synthetic test jaxpr)."""
     gm = eqn.params.get("grid_mapping")
     if gm is None:
         return None
@@ -58,8 +58,7 @@ def static_budget(ctx: RuleContext):
         est = tuning.estimate_pallas_vmem_bytes(*specs)
         peak = max(peak, est)
         if est > budget:
-            info = eqn.params.get("name_and_src_info")
-            name = getattr(info, "name", "") or "pallas_call"
+            name = eqn.params.get("name") or "pallas_call"
             in_blocks = [s for s, _ in specs[0]]
             yield Finding(
                 rule="vmem/static-budget",

@@ -168,14 +168,14 @@ def build_serve_step() -> RuleContext:
 
     from repro.launch.steps import build_serve_step as make_step
     from repro.parallel.rules import rules_for
-    from repro.parallel.sharding import make_mesh_compat, set_mesh_compat
+    from repro.parallel.sharding import auto_mesh
 
     cfg, pm, cache, tok, pos, knobs = _paired_lm_pieces()
-    mesh = make_mesh_compat((1, jax.device_count()), ("data", "model"))
+    mesh = auto_mesh((1, jax.device_count()), ("data", "model"))
     rules = rules_for(cfg, "decode", mesh)
     step = make_step(cfg, mesh, rules, knobs)
     batch = {"tokens": tok, "pos": pos}
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         jaxpr = jax.make_jaxpr(step)(pm, cache, batch)
         hlo = jax.jit(step).lower(pm, cache, batch).compile().as_text()
     return RuleContext(
@@ -258,13 +258,13 @@ def build_sharded_decode() -> RuleContext:
     from repro.launch.steps import wire_serve_cell
     from repro.models import lm as M
     from repro.models.param import unzip
-    from repro.parallel.sharding import make_mesh_compat, set_mesh_compat
+    from repro.parallel.sharding import auto_mesh
 
     cfg = _smoke_lm_cfg()
     params, _ = unzip(M.init_lm(cfg, jax.random.key(0)))
     n = jax.device_count()
     shape = (2, n // 2) if n >= 4 else (1, n)
-    mesh = make_mesh_compat(shape, ("data", "model"))
+    mesh = auto_mesh(shape, ("data", "model"))
     knobs = _paired_knobs()
     cell = wire_serve_cell(
         cfg, params, mesh, batch_size=2, max_seq=32, knobs=knobs
@@ -275,7 +275,7 @@ def build_sharded_decode() -> RuleContext:
         "tokens": jnp.zeros((2, 1), jnp.int32),
         "pos": jnp.asarray([5, 11], jnp.int32),
     }
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         hlo = cell.decode.lower(cell.params, cache, batch).compile().as_text()
     return RuleContext(
         target="sharded_decode",

@@ -21,6 +21,7 @@ from collections.abc import Callable
 from typing import Any
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.cost_model import AsicCostModel, OpCounts
@@ -668,8 +669,8 @@ def pair_params(
                     continue
                 matched.add((sub_path, w_name))
                 arr = np.asarray(sub[w_name])
-                if arr.dtype.kind != "f" or arr.ndim < 3:
-                    continue  # stacked (layers, …) float matrices only
+                if not jnp.issubdtype(arr.dtype, jnp.floating) or arr.ndim < 3:
+                    continue  # stacked (layers, …) float matrices, bf16 included
                 L = arr.shape[0]
                 # MoE expert weights carry a second leading (expert) axis:
                 # pair each expert's (K, F) matrix separately.
@@ -802,8 +803,8 @@ def pair_model_params(
         if not isinstance(leaf, np.ndarray | jax.Array):
             return leaf
         arr = np.asarray(leaf)
-        if arr.dtype.kind != "f" or arr.ndim not in (2, 4):
-            return leaf
+        if not jnp.issubdtype(arr.dtype, jnp.floating) or arr.ndim not in (2, 4):
+            return leaf  # bf16 included (numpy reports its kind as "V")
         if arr.ndim == 4:
             H, Wd, Cin, Cout = arr.shape
             mat = arr.reshape(H * Wd * Cin, Cout)

@@ -13,24 +13,26 @@ vector is never materialized in HBM.  The ``residual=`` epilogue matches the
 paired GEMM kernel's: an fp32 add in VMEM, no standalone residual add op.
 
 Geometry: grid ``(B, nk)`` with the KV-chunk axis innermost (sequential —
-the (m, l, acc) scratch carries across chunks of one slot's cache).  GQA is
-handled by reshaping the query row to ``(KH, G, D)`` in-kernel; scores and
-probabilities batch over KV heads on the MXU.  Masking matches
-``layers._block_mask`` decode semantics exactly: keys at ``pk <= pos``,
-restricted to the sliding window ``pk > pos - window`` when one is set, with
-the first ``n_sink`` (meta-token) positions always visible.  Chunks fully
-outside the mask are skipped via ``pl.when`` without touching the MXU.
+the (m, l, acc) scratch carries across chunks of one slot's cache).  Every
+block is tile-legal for Mosaic: the cache streams as ``(1, k_chunk, KH·D)``
+lane-dense chunks (a free reshape of ``(B, S, KH, D)``), each KV head is a
+128-aligned lane slice of it, and the per-slot positions live in SMEM.  GQA
+scores every query head against each KV head on the MXU and keeps the rows
+of that head's group — two-dimensional dots only, no in-kernel reshapes.
+Masking matches ``layers._block_mask`` decode semantics exactly: keys at
+``pk <= pos``, restricted to the sliding window ``pk > pos - window`` when
+one is set, with the first ``n_sink`` (meta-token) positions always visible.
+Chunks fully outside the mask are skipped via ``pl.when`` without touching
+the MXU; every chunk that runs holds at least one visible key, so the
+running max is finite after it.
 
-The subtractor difference ``o[I] - o[J]`` here operates on the fp32
-VMEM-resident attended values: unlike the standalone paired GEMM (whose
-activations arrive from HBM at input precision, pinned with
-``reduce_precision``), the attended vector never exists at storage precision,
-so the kernel casts it once to the I/O dtype before the projection to keep
-the arithmetic aligned with the unfused reference path.
-
-The index gather in the flush uses ``jnp.take`` on the flattened attended
-vector; on real hardware this folds into a one-hot MXU contraction (the same
-trick the im2col path uses), which interpret mode models exactly.
+The out-projection gathers the attended lanes with one-hot contractions on
+the MXU (Mosaic has no lane gather): per query head, ``o[h] @ (e_I − e_J)``
+yields the head's share of ``o[I] − o[J]`` and ``o[h] @ e_resid`` its
+residual lanes, exactly, since every one-hot column selects one lane.  The
+subtractor difference is then rounded to the I/O dtype before it feeds the
+MXU — the input-precision semantics of the paired GEMM kernel, so the fused
+and unfused decode schedules run the same arithmetic.
 """
 from __future__ import annotations
 
@@ -40,8 +42,9 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash_attention import _SCRATCH, _pad_axis
+from repro.kernels.paired_matmul import _pad_to, mxu_dot, resolve_interpret
 
 
 def decode_attention_ref(
@@ -86,21 +89,22 @@ def _decode_attn_kernel(
     nk: int,
     KH: int,
     G: int,
-    n_cols: int,
+    D: int,
     proj: bool,
     has_residual: bool,
     io_dtype,
 ):
     it = iter(refs)
-    q_ref, k_ref, v_ref, pos_ref = next(it), next(it), next(it), next(it)
+    pos_ref, q_ref, k_ref, v_ref = next(it), next(it), next(it), next(it)
     if proj:
         i_ref, j_ref, r_ref = next(it), next(it), next(it)
         km_ref, wr_ref = next(it), next(it)
     resid_ref = next(it) if has_residual else None
     o_ref, m_ref, l_ref, acc_ref = next(it), next(it), next(it), next(it)
 
+    H = KH * G
     ki = pl.program_id(1)
-    pos = pos_ref[0]
+    pos = pos_ref[pl.program_id(0)]
     base = ki * k_chunk
 
     @pl.when(ki == 0)
@@ -117,17 +121,24 @@ def _decode_attn_kernel(
             hi |= base < n_sink
         live &= hi
 
+    def by_kv_head(fn, shape):
+        """Row-select ``fn(h)`` (computed for all H query rows) into the
+        rows of KV head h's query group."""
+        row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        out = fn(0)
+        for h in range(1, KH):
+            out = jnp.where((row >= h * G) & (row < (h + 1) * G), fn(h), out)
+        return out
+
     @pl.when(live)
     def _compute():
-        D = q_ref.shape[-1]
-        q = q_ref[0].astype(jnp.float32).reshape(KH, G, D)
-        kt = k_ref[0].astype(jnp.float32).transpose(1, 0, 2)  # (KH, C, D)
-        vt = v_ref[0].astype(jnp.float32).transpose(1, 0, 2)
-        s = jax.lax.dot_general(
-            q, kt, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * scale  # (KH, G, C)
-        pk = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        q = q_ref[0]  # (H, D)
+        kv_lanes = lambda ref, h: ref[0, :, h * D:(h + 1) * D]  # (C, D)
+        s = by_kv_head(
+            lambda h: mxu_dot(q, kv_lanes(k_ref, h).astype(q.dtype), 1),
+            (H, k_chunk),
+        ) * scale
+        pk = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         ok = pk <= pos
         if window:
             in_w = pk > pos - window
@@ -135,47 +146,59 @@ def _decode_attn_kernel(
                 in_w |= pk < n_sink
             ok &= in_w
         s = jnp.where(ok, s, -jnp.inf)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(-1))
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(s - m_safe[..., None])
-        p = jnp.where(jnp.isfinite(s), p, 0.0)
-        corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
-        l_ref[...] = l_ref[...] * corr + p.sum(-1)
-        acc_ref[...] = acc_ref[...] * corr[..., None] + jax.lax.dot_general(
-            p, vt, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
+        m_prev = m_ref[...]  # (H, 1)
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + p.sum(-1, keepdims=True)
+        v_dt = v_ref.dtype
+        pv = by_kv_head(
+            lambda h: mxu_dot(p.astype(v_dt), kv_lanes(v_ref, h), 0), (H, D)
         )
+        acc_ref[...] = acc_ref[...] * corr + pv
         m_ref[...] = m_new
 
     @pl.when(ki == nk - 1)
     def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o = acc_ref[...] / l[..., None]  # (KH, G, D) fp32
+        o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)  # (H, D) fp32
         if not proj:
-            o_ref[0] = o.reshape(o_ref.shape[1:]).astype(o_ref.dtype)
+            o_ref[0] = o.astype(o_ref.dtype)
             return
-        # paired out-projection, still in VMEM: gather the flattened
-        # attended vector by the frozen [I | J | resid] metadata and
-        # contract each column block against its subtractor segments
-        of = o.reshape(-1).astype(io_dtype).astype(jnp.float32)  # (H·D,)
-        oi = jnp.take(of, i_ref[...], axis=0)  # (Bw, Pmax)
-        oj = jnp.take(of, j_ref[...], axis=0)
-        orr = jnp.take(of, r_ref[...], axis=0)  # (Bw, Rmax)
-        km = km_ref[...].astype(jnp.float32)  # (Bw, Pmax, bn)
-        wr = wr_ref[...].astype(jnp.float32)  # (Bw, Rmax, bn)
-        y = jnp.einsum("bp,bpn->bn", oi - oj, km,
-                       preferred_element_type=jnp.float32)
-        y += jnp.einsum("br,brn->bn", orr, wr,
-                        preferred_element_type=jnp.float32)
-        y = y.reshape(-1)[:n_cols]
-        if has_residual:
-            # fused skip connection: fp32 add in VMEM, no standalone add op
-            y += resid_ref[0].astype(jnp.float32)
-        o_ref[0] = y.astype(o_ref.dtype)
+        # paired out-projection, still in VMEM: gather the attended lanes by
+        # the frozen [I | J | resid] metadata (one-hot MXU contractions, one
+        # per head's D lanes) and contract each column block against its
+        # subtractor segments
+        o_io = o.astype(io_dtype)
+        Bw, bn = km_ref.shape[0], km_ref.shape[2]
+
+        def gather(w, idx_ref, minus_ref=None):
+            """(1, n) lanes ``o[idx]`` (minus ``o[minus]``) of block w, in
+            the I/O dtype: the subtractor runs at input precision, as in the
+            paired GEMM kernel."""
+            idx = idx_ref[w:w + 1, :]  # (1, n) lane ids into o's H·D
+            lanes = jax.lax.broadcasted_iota(jnp.int32, (D, idx.shape[1]), 0)
+            out = None
+            for h in range(H):
+                sel = (lanes + h * D == idx).astype(io_dtype)
+                if minus_ref is not None:
+                    sel -= (lanes + h * D == minus_ref[w:w + 1, :]).astype(io_dtype)
+                part = mxu_dot(o_io[h:h + 1, :], sel, 0)
+                out = part if out is None else out + part
+            return out.astype(io_dtype)
+
+        for w in range(Bw):
+            y = mxu_dot(gather(w, i_ref, j_ref), km_ref[w].astype(io_dtype), 0)
+            y += mxu_dot(gather(w, r_ref), wr_ref[w].astype(io_dtype), 0)
+            if has_residual:
+                # fused skip connection: fp32 add in VMEM, no standalone add op
+                y += resid_ref[0, :, w * bn:(w + 1) * bn].astype(jnp.float32)
+            o_ref[0, :, w * bn:(w + 1) * bn] = y.astype(o_ref.dtype)
 
 
-def _grid_pieces(q, k_cache, v_cache, pos, k_chunk):
+def _launch(q, k_cache, v_cache, pos, extra_specs, extra_operands, out_cols,
+            out_dtype, *, k_chunk, interpret, window, n_sink, proj, has_residual):
+    """Shared ``pallas_call`` for the bare and the fused kernel: SMEM
+    positions, lane-dense cache chunks, fp32 online-softmax scratch."""
     B, _, H, D = q.shape
     _, S, KH, _ = k_cache.shape
     if H % KH != 0:
@@ -185,10 +208,40 @@ def _grid_pieces(q, k_cache, v_cache, pos, k_chunk):
         )
     k_chunk = min(k_chunk, S)
     nk = -(-S // k_chunk)
-    k_cache = _pad_axis(k_cache, 1, nk * k_chunk)
-    v_cache = _pad_axis(v_cache, 1, nk * k_chunk)
-    pos = pos.astype(jnp.int32)
-    return B, H, D, KH, H // KH, S, k_chunk, nk, k_cache, v_cache, pos
+    # (B, S, KH, D) → (B, S, KH·D) is a free reshape; pad S to the chunk grid
+    k_cache, v_cache = (
+        _pad_to(c.reshape(B, S, KH * D), 1, nk * k_chunk) for c in (k_cache, v_cache)
+    )
+    kernel = functools.partial(
+        _decode_attn_kernel,
+        scale=1.0 / math.sqrt(D), window=window, n_sink=n_sink,
+        k_chunk=k_chunk, nk=nk, KH=KH, G=H // KH, D=D,
+        proj=proj, has_residual=has_residual, io_dtype=q.dtype,
+    )
+    out_shape, out_block = ((B, H, D), (1, H, D)) if out_cols is None else (
+        (B, 1, out_cols), (1, 1, out_cols))
+    return pl.pallas_call(
+        kernel,
+        name="fused_attn_decode",
+        grid=(B, nk),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, H, D), lambda b, ki: (b, 0, 0)),
+            pl.BlockSpec((1, k_chunk, KH * D), lambda b, ki: (b, ki, 0)),
+            pl.BlockSpec((1, k_chunk, KH * D), lambda b, ki: (b, ki, 0)),
+            *extra_specs,
+        ],
+        out_specs=pl.BlockSpec(out_block, lambda b, ki: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
+        scratch_shapes=[
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, D), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=resolve_interpret(interpret),
+    )(pos.astype(jnp.int32), q[:, 0], k_cache, v_cache, *extra_operands)
 
 
 def decode_attention_fwd(
@@ -200,40 +253,18 @@ def decode_attention_fwd(
     window: int = 0,
     n_sink: int = 0,
     k_chunk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Bare fused decode attention: returns the attended (B, 1, H, D) rows.
 
     Same kernel as :func:`fused_decode_attention` minus the out-projection —
     the parity surface the tests pin against ``layers.decode_attention``.
     """
-    (B, H, D, KH, G, S, k_chunk, nk,
-     k_cache, v_cache, pos) = _grid_pieces(q, k_cache, v_cache, pos, k_chunk)
-    kernel = functools.partial(
-        _decode_attn_kernel,
-        scale=1.0 / math.sqrt(D), window=window, n_sink=n_sink,
-        k_chunk=k_chunk, nk=nk, KH=KH, G=G, n_cols=0,
-        proj=False, has_residual=False, io_dtype=q.dtype,
+    out = _launch(
+        q, k_cache, v_cache, pos, [], [], None, q.dtype,
+        k_chunk=k_chunk, interpret=interpret, window=window, n_sink=n_sink,
+        proj=False, has_residual=False,
     )
-    out = pl.pallas_call(
-        kernel,
-        name="fused_attn_decode",
-        grid=(B, nk),
-        in_specs=[
-            pl.BlockSpec((1, H, D), lambda b, ki: (b, 0, 0)),
-            pl.BlockSpec((1, k_chunk, KH, D), lambda b, ki: (b, ki, 0, 0)),
-            pl.BlockSpec((1, k_chunk, KH, D), lambda b, ki: (b, ki, 0, 0)),
-            pl.BlockSpec((1,), lambda b, ki: (b,)),
-        ],
-        out_specs=pl.BlockSpec((1, H, D), lambda b, ki: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        scratch_shapes=[
-            _SCRATCH((KH, G), jnp.float32),
-            _SCRATCH((KH, G), jnp.float32),
-            _SCRATCH((KH, G, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q[:, 0], k_cache, v_cache, pos)
     return out[:, None]
 
 
@@ -254,56 +285,41 @@ def fused_decode_attention(
     window: int = 0,
     n_sink: int = 0,
     k_chunk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Decode attention + paired out-projection in one launch → (B, n_cols).
 
     The attended values live only in VMEM scratch; the single HBM writeback
     per slot is the projected (and residual-added) output row.
     """
-    (B, H, D, KH, G, S, k_chunk, nk,
-     k_cache, v_cache, pos) = _grid_pieces(q, k_cache, v_cache, pos, k_chunk)
+    B = q.shape[0]
     Bw, Pmax = idx_i.shape
     Rmax = idx_r.shape[1]
     bn = kmat.shape[-1]
     assert Bw * bn >= n_cols, (Bw, bn, n_cols)
+    interpret = resolve_interpret(interpret)
+    if not interpret and Bw > 1 and bn % 128:
+        # every block's (Pmax, bn) weights sit whole in VMEM, and a block
+        # narrower than the 128-lane tile pads 128/bn-fold there
+        raise NotImplementedError(
+            f"fused decode attention on the TPU needs out-projection pairing "
+            f"blocks of a multiple of 128 columns (got pair_block_n={bn} over "
+            f"{Bw} blocks): use structured pairing (pair_block_n=0) or "
+            f"attn='xla' with column-blocked pairing")
     has_residual = residual is not None
     if out_dtype is None:
         out_dtype = residual.dtype if has_residual else q.dtype
-    kernel = functools.partial(
-        _decode_attn_kernel,
-        scale=1.0 / math.sqrt(D), window=window, n_sink=n_sink,
-        k_chunk=k_chunk, nk=nk, KH=KH, G=G, n_cols=n_cols,
-        proj=True, has_residual=has_residual, io_dtype=q.dtype,
-    )
-    full2 = pl.BlockSpec((Bw, Pmax), lambda b, ki: (0, 0))
-    full2r = pl.BlockSpec((Bw, Rmax), lambda b, ki: (0, 0))
-    in_specs = [
-        pl.BlockSpec((1, H, D), lambda b, ki: (b, 0, 0)),
-        pl.BlockSpec((1, k_chunk, KH, D), lambda b, ki: (b, ki, 0, 0)),
-        pl.BlockSpec((1, k_chunk, KH, D), lambda b, ki: (b, ki, 0, 0)),
-        pl.BlockSpec((1,), lambda b, ki: (b,)),
-        full2, full2, full2r,
-        pl.BlockSpec((Bw, Pmax, bn), lambda b, ki: (0, 0, 0)),
-        pl.BlockSpec((Bw, Rmax, bn), lambda b, ki: (0, 0, 0)),
-    ]
-    operands = [q[:, 0], k_cache, v_cache, pos,
-                idx_i.astype(jnp.int32), idx_j.astype(jnp.int32),
+    whole = lambda shape: pl.BlockSpec(shape, lambda b, ki: (0,) * len(shape))
+    specs = [whole((Bw, Pmax)), whole((Bw, Pmax)), whole((Bw, Rmax)),
+             whole((Bw, Pmax, bn)), whole((Bw, Rmax, bn))]
+    operands = [idx_i.astype(jnp.int32), idx_j.astype(jnp.int32),
                 idx_r.astype(jnp.int32), kmat, w_res]
     if has_residual:
-        in_specs.append(pl.BlockSpec((1, n_cols), lambda b, ki: (b, 0)))
-        operands.append(residual)
-    return pl.pallas_call(
-        kernel,
-        name="fused_attn_decode",
-        grid=(B, nk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, n_cols), lambda b, ki: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, n_cols), out_dtype),
-        scratch_shapes=[
-            _SCRATCH((KH, G), jnp.float32),
-            _SCRATCH((KH, G), jnp.float32),
-            _SCRATCH((KH, G, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*operands)
+        specs.append(pl.BlockSpec((1, 1, Bw * bn), lambda b, ki: (b, 0, 0)))
+        operands.append(_pad_to(residual, 1, Bw * bn)[:, None])
+    out = _launch(
+        q, k_cache, v_cache, pos, specs, operands, Bw * bn, out_dtype,
+        k_chunk=k_chunk, interpret=interpret, window=window, n_sink=n_sink,
+        proj=True, has_residual=has_residual,
+    )
+    return out[:, 0, :n_cols]

@@ -27,25 +27,9 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU backend scratch spaces
-    from jax.experimental.pallas import tpu as pltpu
-
-    _SCRATCH = pltpu.VMEM
-except Exception:  # pragma: no cover - exercised via monkeypatch in tests
-    # Backend-neutral fallback: a MemoryRef in the ANY space is a callable
-    # with the same (shape, dtype) signature as pltpu.VMEM and is accepted
-    # by ``scratch_shapes`` in interpret mode, so the kernels keep working
-    # when the TPU plugin namespace is absent.
-    _SCRATCH = functools.partial(pl.MemoryRef, memory_space=pl.MemorySpace.ANY)
-
-
-def _pad_axis(arr: jax.Array, axis: int, size: int) -> jax.Array:
-    if arr.shape[axis] == size:
-        return arr
-    pads = [(0, 0)] * arr.ndim
-    pads[axis] = (0, size - arr.shape[axis])
-    return jnp.pad(arr, pads)
+from repro.kernels.paired_matmul import _pad_to, resolve_interpret
 
 
 def _flash_fwd_kernel(
@@ -108,7 +92,7 @@ def flash_attention_fwd(
     causal: bool = True,
     q_chunk: int = 512,
     k_chunk: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Fused flash-attention forward. Returns (B, Sq, H, D) in q.dtype."""
     B, Sq, H, D = q.shape
@@ -127,9 +111,9 @@ def flash_attention_fwd(
     # finite garbage that the final slice drops.
     nq, nk = -(-Sq // q_chunk), -(-Sk // k_chunk)
     Sq_p, Sk_p = nq * q_chunk, nk * k_chunk
-    q = _pad_axis(q, 1, Sq_p)
-    k = _pad_axis(k, 1, Sk_p)
-    v = _pad_axis(v, 1, Sk_p)
+    q = _pad_to(q, 1, Sq_p)
+    k = _pad_to(k, 1, Sk_p)
+    v = _pad_to(v, 1, Sk_p)
 
     # layout: (B, H, S, D) so blocks are (1, 1, chunk, D)
     qt = q.transpose(0, 2, 1, 3)
@@ -142,9 +126,9 @@ def flash_attention_fwd(
         valid_k=Sk,
     )
     scratch = [
-        _SCRATCH((q_chunk,), jnp.float32),
-        _SCRATCH((q_chunk,), jnp.float32),
-        _SCRATCH((q_chunk, D), jnp.float32),
+        pltpu.VMEM((q_chunk,), jnp.float32),
+        pltpu.VMEM((q_chunk,), jnp.float32),
+        pltpu.VMEM((q_chunk, D), jnp.float32),
     ]
     out = pl.pallas_call(
         kernel,
@@ -157,6 +141,6 @@ def flash_attention_fwd(
         out_specs=pl.BlockSpec((1, 1, q_chunk, D), lambda b, h, qi, ki: (b, h, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq_p, D), q.dtype),
         scratch_shapes=scratch,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)[:, :Sq]

@@ -34,10 +34,24 @@ from repro.kernels.paired_matmul import (
     paired_matmul_blocked_pallas,
     paired_matmul_pallas,
 )
+from repro.parallel.sharding import current_mesh
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def on_mesh(launch):
+    """``launch`` (a kernel call) as the active mesh can run it.
+
+    XLA cannot partition a compiled Mosaic kernel, so inside a sharded step
+    the kernel runs whole on every device of the mesh, its operands gathered
+    (a ``shard_map`` over replicated specs): correct, not yet sharded.  The
+    interpreted kernel takes the same path, so a CPU mesh runs the program
+    a TPU mesh runs.
+    """
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return launch
+    P = jax.sharding.PartitionSpec
+    return jax.shard_map(launch, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
 
 
 @functools.partial(
@@ -70,7 +84,6 @@ def paired_matmul(
     ``(4, M, K)`` and the fused 2×2 reduction happens in VMEM (see
     paired_matmul_pallas); ``residual`` is then the pooled ``(M, N)`` map.
     """
-    interp = (not _on_tpu()) if interpret is None else interpret
     has_pool = pool != "none"
     if has_pool:
         assert x.ndim == 3, f"pool={pool!r} expects (4, M, K) x, got {x.shape}"
@@ -90,7 +103,7 @@ def paired_matmul(
     y = paired_matmul_pallas(
         x2, kmat, w_res, bias, residual=res2,
         block_m=tiles.block_m, block_n=tiles.block_n, block_k=tiles.block_k,
-        activation=activation, pool=pool, interpret=interp,
+        activation=activation, pool=pool, interpret=interpret,
     )
     # pooled output is already (M_pooled, N); otherwise restore the lead dims
     # (incl. the 1-D (K,) → (N,) case, where lead == ())
@@ -114,7 +127,6 @@ def dense_matmul(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Plain K-tiled GEMM with the same tiling/epilogue as the paired kernel."""
-    interp = (not _on_tpu()) if interpret is None else interpret
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     res2 = None if residual is None else residual.reshape(-1, residual.shape[-1])
@@ -127,7 +139,7 @@ def dense_matmul(
     y = dense_matmul_pallas(
         x2, w, bias, residual=res2,
         block_m=tiles.block_m, block_n=tiles.block_n, block_k=tiles.block_k,
-        activation=activation, interpret=interp,
+        activation=activation, interpret=interpret,
     )
     return y.reshape(*lead, y.shape[-1])
 
@@ -162,7 +174,6 @@ def paired_matmul_blocked(
     ``block_k = 0`` resolve through the tile cache / heuristic under a
     blocked cache key.
     """
-    interp = (not _on_tpu()) if interpret is None else interpret
     B, P, bn = kmat.shape
     R = w_res.shape[1]
     tiles = tuning.resolve_blocks(
@@ -174,7 +185,7 @@ def paired_matmul_blocked(
     return paired_matmul_blocked_pallas(
         x, kmat, w_res, bias, residual=residual,
         n_cols=n_cols, block_m=tiles.block_m, block_k=tiles.block_k,
-        activation=activation, pool=pool, interpret=interp,
+        activation=activation, pool=pool, interpret=interpret,
     )
 
 
@@ -231,11 +242,11 @@ def _fused_dense_grad(activation, block_m, block_n, block_k, interpret):
     from repro.kernels.paired_matmul import ACTIVATIONS
 
     def primal(x, w, b):
-        return dense_matmul(
-            x, w, b, activation=activation,
+        return on_mesh(functools.partial(
+            dense_matmul, activation=activation,
             block_m=block_m, block_n=block_n, block_k=block_k,
             interpret=interpret,
-        )
+        ))(x, w, b)
 
     @jax.custom_vjp
     def f(x, w, b):
@@ -358,6 +369,39 @@ def fold_lm_weight(w2: jax.Array, meta: dict, pair_block_n: int = 0) -> jax.Arra
     )
 
 
+def fold_lm_params(params, pair_block_n: int = 0):
+    """The dense tree a paired LM tree is equivalent to.
+
+    Every stacked decoder weight that carries a ``<name>_pairing`` sibling
+    becomes its per-layer :func:`fold_lm_weight` and the metadata goes: the
+    plain XLA-path reference that ``gemm="pallas_paired"`` is held to at any
+    rounding.  Expert (MoE) weights are not covered.
+    """
+    from repro.core.transform import _lm_weight_matrix_shape
+
+    def fold(name, w, meta):
+        K, N = _lm_weight_matrix_shape(name, w.shape[1:])
+        one = lambda w1, m1: fold_lm_weight(w1.reshape(K, N), m1, pair_block_n)
+        return jax.vmap(one)(w, meta).reshape(w.shape)
+
+    def walk(node):
+        if isinstance(node, list):
+            return [walk(x) for x in node]
+        if not isinstance(node, dict):
+            return node
+        if "moe" in node and has_pairing(node["moe"]):
+            raise NotImplementedError("fold_lm_params does not fold expert weights")
+        return {
+            k: fold(k, v, node[k + "_pairing"]) if k + "_pairing" in node else walk(v)
+            for k, v in node.items() if not k.endswith("_pairing")
+        }
+
+    def has_pairing(node):
+        return isinstance(node, dict) and any(k.endswith("_pairing") for k in node)
+
+    return walk(params)
+
+
 @functools.lru_cache(maxsize=None)
 def _fused_paired_dense_grad(
     activation, blocked, pair_block_n, block_m, block_n, block_k, interpret
@@ -378,20 +422,18 @@ def _fused_paired_dense_grad(
             xg = jnp.moveaxis(jnp.take(x2, perm, axis=-1), -2, 0)  # (B, M, K')
             kmat, w_res = _lm_blocked_segments(w2, meta, pair_block_n)
             res2 = None if res is None else res.reshape(-1, N)
-            y = paired_matmul_blocked(
-                xg, kmat.astype(x.dtype), w_res.astype(x.dtype), b, res2,
-                n_cols=N, activation=activation,
+            y = on_mesh(functools.partial(
+                paired_matmul_blocked, n_cols=N, activation=activation,
                 block_m=block_m, block_k=block_k, interpret=interpret,
-            )
+            ))(xg, kmat.astype(x.dtype), w_res.astype(x.dtype), b, res2)
             return y.reshape(*x.shape[:-1], N)
         xg = jnp.take(x, perm, axis=-1)
         kmat, w_res = _lm_structured_segments(w2, meta)
-        return paired_matmul(
-            xg, kmat.astype(x.dtype), w_res.astype(x.dtype), b, res,
-            activation=activation,
+        return on_mesh(functools.partial(
+            paired_matmul, activation=activation,
             block_m=block_m, block_n=block_n, block_k=block_k,
             interpret=interpret,
-        )
+        ))(xg, kmat.astype(x.dtype), w_res.astype(x.dtype), b, res)
 
     def ref(x, w2, b, res, meta):
         wf = fold_lm_weight(w2, meta, pair_block_n)
@@ -549,11 +591,10 @@ def _fused_paired_expert_dense_grad(
             xg = xg.reshape(E * Bc, x.shape[-2], -1)
         else:
             xg = jnp.moveaxis(jnp.take(x, perm, axis=-1), -2, 0)  # (E·Bc, M, K')
-        y = paired_matmul_blocked(
-            xg, kmat.astype(x.dtype), w_res.astype(x.dtype),
-            n_cols=E * Bc * bn, activation=activation,
+        y = on_mesh(functools.partial(
+            paired_matmul_blocked, n_cols=E * Bc * bn, activation=activation,
             block_m=block_m, block_k=block_k, interpret=interpret,
-        )
+        ))(xg, kmat.astype(x.dtype), w_res.astype(x.dtype))
         return y.reshape(x.shape[-2], E, Bc * bn)[..., :F]
 
     def ref(x, w, meta):
@@ -690,7 +731,7 @@ def _fused_attn_decode_grad(
             q, k_cache, v_cache, pos, idx_i, idx_j, idx_r,
             kmat.astype(q.dtype), w_res.astype(q.dtype), res2,
             n_cols=N, window=window, n_sink=n_sink, k_chunk=k_chunk,
-            interpret=True if interpret is None else interpret,
+            interpret=interpret,
         )
         return y[:, None]  # (B, 1, N)
 

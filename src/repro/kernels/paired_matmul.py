@@ -105,8 +105,10 @@ replication factor ``n_blocks`` (the paper's per-column dataflow at
 subtract schedule); ``block_n`` is the knob trading that bandwidth against
 pairing rate.
 
-``interpret=True`` executes the same kernel body with jnp semantics on CPU —
-that is how the kernel is validated in this container (TPU is the target).
+``interpret=None`` (every entry's default) resolves through
+:func:`resolve_interpret`: the compiled Mosaic kernel on a TPU backend, the
+Pallas interpreter executing the same kernel body anywhere else (that is how
+the CPU test suite validates it).
 """
 from __future__ import annotations
 
@@ -134,6 +136,29 @@ POOLS: dict[str, Callable] = {
     "avg2": lambda a: a.mean(axis=0),
 }
 POOL_WINDOW = 4  # 2×2 — the only window geometry LeNet (and the paper) uses
+
+
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """The one place ``interpret=None`` is resolved for every kernel entry
+    and policy: compile the Mosaic kernel on a TPU backend, run the Pallas
+    interpreter anywhere else.  An explicit bool is honoured as given."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
+
+
+def mxu_dot(a: jax.Array, b: jax.Array, contract_b: int = 0) -> jax.Array:
+    """2-D MXU contraction of ``a (m, k)`` with ``b`` — ``(k, n)`` at
+    ``contract_b=0``, ``(n, k)`` at ``contract_b=1`` — accumulating in fp32.
+    fp32 operands contract at full fp32 precision (the TPU's default would
+    round them to bf16 for a single MXU pass); bf16 products are exact
+    either way."""
+    assert a.dtype == b.dtype, (a.dtype, b.dtype)
+    prec = jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    return jax.lax.dot_general(
+        a, b, (((1,), (contract_b,)), ((), ())),
+        precision=prec, preferred_element_type=jnp.float32,
+    )
 
 
 def _apply_epilogue(acc, bias_block, activation: str):
@@ -193,10 +218,14 @@ def _build_paired_call(
     HBM writeback.  ``Mp`` then counts *pooled* output rows.
 
     ``n_blocks > 0`` selects the column-blocked layout (module docstring,
-    "Column-blocked layout"): every activation/weight operand carries a
-    leading block axis indexed by the grid n-step (block shape 1), so each
-    n-step contracts against its own ``[I | J | resid]`` segment metadata;
-    the grid n extent is ``n_blocks`` and ``Np == n_blocks · bn``.
+    "Column-blocked layout"): every operand carries a leading block axis
+    indexed by the grid n-step (block shape 1), so each n-step contracts
+    against its own ``[I | J | resid]`` segment metadata; the grid n extent
+    is ``n_blocks`` and ``Np == n_blocks · bn``.  The output, bias and
+    residual are block-major too — ``(n_blocks, Mp, bn)`` and ``(n_blocks,
+    1, bn)`` — so each block's trailing dims are whole array dims and the
+    layout is tile-legal on the TPU for any ``bn``, down to the paper's
+    per-column ``bn = 1``.
     """
     has_pairs = nkp > 0
     has_resid = nkr > 0
@@ -251,6 +280,8 @@ def _build_paired_call(
         def w_tile(ref):
             return ref[0] if blocked else ref[...]
 
+        out_tile = w_tile  # output-space blocks (bias, residual) likewise
+
         def acc_add(w, val):
             if has_pool:
                 acc_ref[w] = acc_ref[w] + val
@@ -270,9 +301,7 @@ def _build_paired_call(
                 km = cast(w_tile(km_ref))
                 for w in range(W):
                     diff = sub(x_at(xi_ref, w), x_at(xj_ref, w))
-                    acc_add(w, jnp.dot(
-                        cast(diff), km, preferred_element_type=jnp.float32,
-                    ))
+                    acc_add(w, mxu_dot(cast(diff), km))
 
             if has_resid:
                 pl.when(k < nkp)(paired_step)
@@ -284,10 +313,7 @@ def _build_paired_call(
             def resid_step():
                 wr = cast(w_tile(wr_ref))
                 for w in range(W):
-                    acc_add(w, jnp.dot(
-                        cast(x_at(xr_ref, w)), wr,
-                        preferred_element_type=jnp.float32,
-                    ))
+                    acc_add(w, mxu_dot(cast(x_at(xr_ref, w)), wr))
 
             if has_pairs:
                 pl.when(k >= nkp)(resid_step)
@@ -296,7 +322,7 @@ def _build_paired_call(
 
         @pl.when(k == nk - 1)
         def _flush():
-            bias_block = b_ref[...] if has_bias else None
+            bias_block = out_tile(b_ref) if has_bias else None
             acc = _apply_epilogue(acc_ref[...], bias_block, activation)
             if has_pool:
                 acc = POOLS[pool](acc)  # (4, bm, bn) → (bm, bn) in VMEM
@@ -304,8 +330,11 @@ def _build_paired_call(
                 # fused skip connection: fp32 add after bias/activation/pool,
                 # still inside VMEM — the residual never costs its own HBM
                 # round-trip through a standalone add op
-                acc = acc + r_ref[...].astype(jnp.float32)
-            o_ref[...] = acc.astype(o_ref.dtype)
+                acc = acc + out_tile(r_ref).astype(jnp.float32)
+            if blocked:
+                o_ref[0] = acc.astype(o_ref.dtype)
+            else:
+                o_ref[...] = acc.astype(o_ref.dtype)
 
     # --- block specs: each segment's index map clamps into its own range ---
     # (with pooling, activation blocks carry the full window axis up front;
@@ -334,21 +363,19 @@ def _build_paired_call(
     if has_resid:
         rk = lambda k: jnp.clip(k - nkp, 0, nkr - 1)
         in_specs += [x_spec(bkr, rk), w_spec(bkr, rk)]
+    if blocked:
+        out_spec = pl.BlockSpec((1, bm, bn), lambda m, n, k: (n, m, 0))
+        bias_spec = pl.BlockSpec((1, 1, bn), lambda m, n, k: (n, 0, 0))
+        out_shape = (n_blocks, Mp, bn)
+    else:
+        out_spec = pl.BlockSpec((bm, bn), lambda m, n, k: (m, n))
+        bias_spec = pl.BlockSpec((1, bn), lambda m, n, k: (0, n))
+        out_shape = (Mp, Np)
     if has_bias:
-        in_specs.append(pl.BlockSpec((1, bn), lambda m, n, k: (0, n)))
+        in_specs.append(bias_spec)
     if has_residual:
         # output-space operand: indexed exactly like the output tile
-        in_specs.append(pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)))
-
-    kwargs = {}
-    if not interpret:
-        # k must iterate sequentially per output tile (the accumulator
-        # carries across k-steps); m/n tiles are independent.
-        params_cls = getattr(pltpu, "TPUCompilerParams", None)
-        if params_cls is not None:
-            kwargs["compiler_params"] = params_cls(
-                dimension_semantics=("parallel", "parallel", "arbitrary")
-            )
+        in_specs.append(out_spec)
 
     # name the kernel by its segments: profiles (and the dtype analysis rule,
     # which pins reduce_precision on low-precision *subtractor* kernels) key
@@ -365,11 +392,14 @@ def _build_paired_call(
         name=name,
         grid=(Mp // bm, n_blocks if blocked else Np // bn, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
+        out_specs=out_spec,
+        out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
         scratch_shapes=[pltpu.VMEM(acc_shape, jnp.float32)],
+        # k must iterate sequentially per output tile (the accumulator
+        # carries across k-steps); m/n tiles are independent
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **kwargs,
     )
 
 
@@ -385,7 +415,7 @@ def paired_matmul_pallas(
     block_k: int = 512,
     activation: str = "none",
     pool: str = "none",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """K-tiled fused subtract-then-MAC GEMM with epilogue. Returns (M, N).
 
@@ -472,7 +502,7 @@ def paired_matmul_pallas(
         bm=bm, bn=bn, nkp=nkp, bkp=bkp, nkr=nkr, bkr=bkr,
         has_bias=bias is not None, has_residual=residual is not None,
         activation=activation, pool=pool,
-        Mp=Mp, Np=Np, out_dtype=x.dtype, interpret=interpret,
+        Mp=Mp, Np=Np, out_dtype=x.dtype, interpret=resolve_interpret(interpret),
     )
     out = call(*operands)
     return out[:M, :N]
@@ -490,7 +520,7 @@ def paired_matmul_blocked_pallas(
     block_k: int = 512,
     activation: str = "none",
     pool: str = "none",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Column-blocked K-tiled paired GEMM. Returns (M, n_cols).
 
@@ -576,19 +606,22 @@ def paired_matmul_blocked_pallas(
             _pad_to(_pad_to(xr, m_axis, Mp), k_axis, Rp),
             _pad_to(w_res, 1, Rp),
         ]
+    # output-space operands go block-major, like the kernel's output
     if bias is not None:
-        operands.append(_pad_to(bias[None], 1, Np))
+        operands.append(_pad_to(bias, 0, Np).reshape(B, 1, bn))
     if residual is not None:
-        operands.append(_pad_to(_pad_to(residual, 0, Mp), 1, Np))
+        res = _pad_to(_pad_to(residual, 0, Mp), 1, Np)
+        operands.append(res.reshape(Mp, B, bn).transpose(1, 0, 2))
 
     call = _build_paired_call(
         bm=bm, bn=bn, nkp=nkp, bkp=bkp, nkr=nkr, bkr=bkr,
         has_bias=bias is not None, has_residual=residual is not None,
         activation=activation, pool=pool,
-        Mp=Mp, Np=Np, out_dtype=x.dtype, interpret=interpret, n_blocks=B,
+        Mp=Mp, Np=Np, out_dtype=x.dtype, interpret=resolve_interpret(interpret),
+        n_blocks=B,
     )
-    out = call(*operands)
-    return out[:M, :n_cols]
+    out = call(*operands)  # (B, Mp, bn)
+    return out.transpose(1, 0, 2).reshape(Mp, Np)[:M, :n_cols]
 
 
 def dense_matmul_pallas(
@@ -601,7 +634,7 @@ def dense_matmul_pallas(
     block_n: int = 128,
     block_k: int = 512,
     activation: str = "none",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Baseline K-tiled GEMM with identical tiling + epilogue fusion.
 

@@ -103,8 +103,8 @@ def estimate_pallas_vmem_bytes(
     The generic counterpart of :func:`kernel_vmem_bytes` (which models the
     paired kernel's named streams): each argument is an iterable of
     ``(block_shape, dtype_bytes)``.  Streamed inputs are double-buffered,
-    outputs and scratch are resident once.  ``None`` entries in a block shape
-    (squeezed grid dims) occupy one element.  This is what the static
+    outputs and scratch are resident once.  Squeezed block dims (``None``,
+    ``Squeezed()``) occupy one element.  This is what the static
     analysis pass charges against :data:`VMEM_BUDGET_BYTES` before anything
     runs.
     """
@@ -112,7 +112,10 @@ def estimate_pallas_vmem_bytes(
     def tile(shape, nbytes) -> int:
         n = 1
         for d in shape:
-            n *= int(d) if d is not None else 1
+            # a pallas block dim is Blocked(block_size) or a bare int; None
+            # and Squeezed() are one element
+            size = getattr(d, "block_size", d)
+            n *= size if isinstance(size, int) else 1
         return n * nbytes
 
     buf = 2 if double_buffer else 1

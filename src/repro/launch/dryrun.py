@@ -27,6 +27,7 @@ import time
 import traceback
 from pathlib import Path
 
+import jax
 
 from repro.configs import ALL_ARCHS, get_config
 from repro.configs.base import SHAPES, supports_shape
@@ -34,8 +35,8 @@ from repro.launch.inputs import batch_specs
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import wire_cell
 from repro.models.lm import PerfKnobs
-from repro.parallel.hlo import analyze, xla_cost_analysis
-from repro.parallel.sharding import record_spec_fallbacks, set_mesh_compat
+from repro.parallel.hlo import analyze
+from repro.parallel.sharding import record_spec_fallbacks
 
 RESULTS_DIR = Path(__file__).resolve().parents[3] / "benchmarks" / "results" / "dryrun"
 
@@ -85,14 +86,14 @@ def run_cell(
                 mode=shape.kind,
                 knobs=knobs,
             )
-            with set_mesh_compat(mesh):
+            with jax.set_mesh(mesh):
                 lowered = cell.lower()
                 t_lower = time.time() - t0
                 compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
 
         mem = compiled.memory_analysis()
-        cost = xla_cost_analysis(compiled)
+        cost = compiled.cost_analysis() or {}
         hlo = compiled.as_text()
         # trip-count-aware HLO accounting (xla cost_analysis counts scan
         # bodies once — see parallel/hlo.py)

@@ -18,13 +18,13 @@ from __future__ import annotations
 
 import jax
 
-from repro.parallel.sharding import make_mesh_compat
+from repro.parallel.sharding import auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def data_axes(mesh: jax.sharding.Mesh) -> tuple[str, ...]:

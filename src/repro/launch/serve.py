@@ -21,17 +21,34 @@ import dataclasses
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.core.transform import pair_model_params
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm as M
 from repro.models.lenet import CONV_IMPLS
 from repro.models.param import unzip
 from repro.serving.engine import ServeEngine
 
 
+def serving_params(cfg, seed: int = 0):
+    """Seeded weights as the server holds them: weight matrices in the
+    config's compute dtype (``cfg.dtype``), vectors (norm scales, biases)
+    in fp32.  Made op by op: one jitted init of a full-depth tree takes
+    minutes to compile."""
+    cdt = jnp.dtype(cfg.dtype)
+    params, _ = unzip(M.init_lm(cfg, jax.random.key(seed)))
+    return jax.tree.map(
+        lambda a: a.astype(cdt)
+        if a.ndim >= 2 and jnp.issubdtype(a.dtype, jnp.floating) else a,
+        params,
+    )
+
+
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -103,7 +120,7 @@ def main() -> None:
     args = ap.parse_args()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    params, _ = unzip(M.init_lm(cfg, jax.random.key(0)))
+    params = serving_params(cfg)
     if args.paired_rounding > 0:
         mode = "column_blocked" if args.pair_block_n >= 1 else "per_column"
         params, report = pair_model_params(

@@ -27,16 +27,18 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, get_smoke_config
 from repro.data.tokens import token_batches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import build_train_step
 from repro.models import lm as M
 from repro.models.param import unzip
 from repro.parallel.rules import rules_for
-from repro.parallel.sharding import make_mesh_compat, set_mesh_compat, shardings_for
+from repro.parallel.sharding import auto_mesh, shardings_for
 from repro.train.checkpoint import latest_step, restore_checkpoint, save_checkpoint
 from repro.train.optimizer import adamw, cosine_schedule
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true", help="reduced config (CPU)")
@@ -58,9 +60,9 @@ def main() -> None:
     if args.mesh:
         shape = tuple(int(x) for x in args.mesh.split("x"))
         names = ("data", "model")[: len(shape)]
-        mesh = make_mesh_compat(shape, names)
+        mesh = auto_mesh(shape, names)
     else:
-        mesh = make_mesh_compat((jax.device_count(), 1), ("data", "model"))
+        mesh = auto_mesh((jax.device_count(), 1), ("data", "model"))
     rules = rules_for(cfg, "train", mesh)
 
     tree = M.init_lm(cfg, jax.random.key(0))
@@ -93,7 +95,7 @@ def main() -> None:
 
     data = token_batches(args.batch, args.seq, cfg.vocab, seed=1, start_step=start)
     t0 = time.time()
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         for i, (tok, lab) in enumerate(data, start=start):
             if i >= args.steps:
                 break

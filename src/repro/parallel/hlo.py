@@ -201,20 +201,6 @@ def _group_size(line: str) -> int:
     return 2
 
 
-def xla_cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` normalized to a flat dict.
-
-    Depending on JAX version the method returns a dict, a single-element
-    list of dicts (one per partition), or None; every consumer of compiled
-    cost in this repo goes through here so the shape difference never
-    leaks.
-    """
-    cost = compiled.cost_analysis() if hasattr(compiled, "cost_analysis") else compiled
-    if isinstance(cost, list | tuple):
-        cost = cost[0] if cost else {}
-    return dict(cost) if cost else {}
-
-
 @dataclasses.dataclass
 class HloCost:
     flops: float

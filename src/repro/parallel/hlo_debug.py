@@ -8,9 +8,9 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 import argparse
 from collections import defaultdict
 
+import jax
 
 from repro.parallel import hlo as H
-from repro.parallel.sharding import set_mesh_compat
 
 
 def top_contributors(text: str, k: int = 15):
@@ -87,7 +87,7 @@ def main():
     mesh = make_production_mesh(multi_pod=args.multi_pod)
     cell = wire_cell(cfg, mesh, seq_len=shape.seq_len, global_batch=shape.global_batch,
                      mode=shape.kind, knobs=PerfKnobs(q_chunk=args.q_chunk, k_chunk=args.k_chunk))
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         compiled = cell.lower().compile()
     top_contributors(compiled.as_text(), args.top)
 

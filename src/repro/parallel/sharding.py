@@ -28,45 +28,18 @@ from collections.abc import Callable, Mapping, Sequence
 from typing import Any
 
 import jax
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def make_mesh_compat(axis_shapes, axis_names, *, explicit: bool = False) -> Mesh:
-    """``jax.make_mesh`` across JAX versions.
-
-    Newer JAX grew ``jax.sharding.AxisType`` and a ``make_mesh(...,
-    axis_types=...)`` parameter; the pinned JAX here has neither.  Feature-
-    detect both and fall back to plain ``Mesh`` construction so callers
-    (tests, launch scripts) never touch ``jax.sharding.AxisType`` directly.
-    """
-    axis_shapes = tuple(axis_shapes)
-    axis_names = tuple(axis_names)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None and hasattr(jax, "make_mesh"):
-        kind = axis_type.Explicit if explicit else axis_type.Auto
-        # make_mesh may predate axis_types — fall through on TypeError
-        with contextlib.suppress(TypeError):
-            return jax.make_mesh(
-                axis_shapes, axis_names, axis_types=(kind,) * len(axis_names)
-            )
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(axis_shapes, axis_names)
-    n = int(np.prod(axis_shapes))
-    devices = np.asarray(jax.devices()[:n]).reshape(axis_shapes)
-    return Mesh(devices, axis_names)
-
-
-def set_mesh_compat(mesh: Mesh):
-    """``jax.set_mesh(mesh)`` across JAX versions.
-
-    Newer JAX installs the mesh via ``jax.set_mesh``; on the pinned JAX the
-    ``Mesh`` object itself is the context manager with the same effect for
-    everything this repo does (jit with NamedShardings + shard_map).
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+def auto_mesh(axis_shapes, axis_names, devices=None) -> Mesh:
+    """``jax.make_mesh`` with *Auto* axes (JAX's default is Explicit): every
+    sharding here comes from the logical rules — jit in/out shardings and
+    :func:`constrain` — not from sharding-in-types."""
+    names = tuple(axis_names)
+    return jax.make_mesh(
+        tuple(axis_shapes), names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(names), devices=devices,
+    )
 
 
 # Resolution priority: earlier names win a contested mesh axis.

@@ -120,6 +120,9 @@ class ServeEngine:
                     self.params, self.knobs.pair_rounding,
                     mode=mode, block_n=block_n,
                 )
+                # the metadata comes back as host arrays: place it once,
+                # not on every step call
+                self.params = jax.device_put(self.params)
 
         # knobs.gemm == "pallas" routes every layers.dense GEMM in the traced
         # step through the fused K-tiled kernel ("pallas_paired" routes the
@@ -200,6 +203,14 @@ class ServeEngine:
         self.tokens = self.tokens.at[slot, 0].set(next_tok)
         self.active[slot] = True
         return next_tok
+
+    def prefill_logits(self, prompt: np.ndarray) -> np.ndarray:
+        """(vocab,) next-token logits after prefilling ``prompt`` — the
+        prefill :meth:`add_request` runs, without touching any slot (a
+        parity probe on the engine's own compiled step)."""
+        batch = {"tokens": jnp.asarray(prompt[None, :], jnp.int32)}
+        last_logits, _ = self._prefill(self.params, batch)
+        return np.asarray(last_logits[0, -1, : self.cfg.vocab])
 
     def step(self, sample: Callable | None = None) -> np.ndarray:
         """One decode step for every active slot. Returns (batch,) next tokens.

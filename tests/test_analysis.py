@@ -226,7 +226,7 @@ def test_reduce_precision_required_on_bf16_paired_kernels():
     def paired_eqn(kernel_eqns):
         e = _fake_eqn("pallas_call", {
             "jaxpr": _fake_jaxpr(kernel_eqns),
-            "name_and_src_info": types.SimpleNamespace(name="paired_matmul"),
+            "name": "paired_matmul",
         })
         aval = types.SimpleNamespace(dtype=jnp.dtype(jnp.bfloat16), shape=(4, 4))
         e.invars = (types.SimpleNamespace(aval=aval),)

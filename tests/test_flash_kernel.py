@@ -1,14 +1,10 @@
 """Pallas flash-attention kernel vs the pure-jnp blocked reference."""
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import pallas as pl
 
 from repro.kernels import decode_attention as da
-from repro.kernels import flash_attention as fa
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.models import layers as L
 from repro.models.layers import flash_attention
@@ -76,31 +72,6 @@ def test_flash_kernel_ragged_lengths(Sq, Sk, causal):
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
-
-
-def test_scratch_fallback_memref(monkeypatch):
-    """The backend-neutral ``_SCRATCH`` fallback (taken when the pltpu
-    namespace is absent) must actually work as a ``scratch_shapes`` entry —
-    the old ``None`` sentinel TypeError'd on first kernel call."""
-    fallback = functools.partial(pl.MemoryRef, memory_space=pl.MemorySpace.ANY)
-    monkeypatch.setattr(fa, "_SCRATCH", fallback)
-    monkeypatch.setattr(da, "_SCRATCH", fallback)
-    rng = np.random.default_rng(3)
-    q = jnp.asarray(rng.normal(size=(1, 32, 2, 16)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(1, 32, 2, 16)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(1, 32, 2, 16)), jnp.float32)
-    got = fa.flash_attention_fwd(q, k, v, causal=True, q_chunk=16, k_chunk=16)
-    np.testing.assert_allclose(np.asarray(got),
-                               np.asarray(_mha_ref(q, k, v, True)),
-                               rtol=2e-4, atol=2e-4)
-    qd = jnp.asarray(rng.normal(size=(2, 1, 4, 16)), jnp.float32)
-    kc = jnp.asarray(rng.normal(size=(2, 16, 2, 16)), jnp.float32)
-    vc = jnp.asarray(rng.normal(size=(2, 16, 2, 16)), jnp.float32)
-    pos = jnp.asarray([5, 11], jnp.int32)
-    got_d = da.decode_attention_fwd(qd, kc, vc, pos, k_chunk=8)
-    want_d = L.decode_attention(qd, kc, vc, pos)
-    np.testing.assert_allclose(np.asarray(got_d), np.asarray(want_d),
-                               rtol=2e-5, atol=2e-5)
 
 
 def test_gqa_non_divisible_heads_raise():

@@ -4,14 +4,13 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.parallel.hlo import analyze, parse_hlo, xla_cost_analysis
+from repro.parallel.hlo import analyze, parse_hlo
 
 
 def _compile_text(fn, *args):
     lowered = jax.jit(fn).lower(*args)
     compiled = lowered.compile()
-    # xla_cost_analysis normalizes the list-of-dicts return of older JAX
-    return compiled.as_text(), xla_cost_analysis(compiled)
+    return compiled.as_text(), compiled.cost_analysis()
 
 
 def test_matmul_flops_match_xla():

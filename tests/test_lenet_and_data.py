@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core.transform import pair_model_params
 from repro.data.mnist import load_mnist, pad_to_32, synthetic_mnist, batches
@@ -137,3 +138,14 @@ def test_pair_model_params_structured_mode():
     paired, report = pair_model_params(params, rounding=0.2, mode="structured", keep_pairings=True)
     assert report.leaves[0].pairing is not None
     assert paired["w"].shape == (64, 32)
+
+
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
+def test_pair_model_params_pairs_bf16_like_fp32(dtype):
+    """bf16 leaves (the serving dtype) are paired, not skipped as non-float."""
+    w = np.random.default_rng(0).normal(size=(64, 32)).astype(np.float32)
+    paired, report = pair_model_params({"w": w.astype(dtype)}, rounding=0.2, min_dim=4)
+    _, want = pair_model_params({"w": w.astype(dtype).astype(np.float32)},
+                                rounding=0.2, min_dim=4)
+    assert report.total_pairs == want.total_pairs > 0
+    assert paired["w"].dtype == dtype

@@ -24,6 +24,7 @@ from repro.core.transform import (
     pair_lm_params,
 )
 from repro.kernels.ops import (
+    fold_lm_params,
     fold_lm_weight,
     fused_paired_dense,
     pallas_paired_gemm,
@@ -257,3 +258,29 @@ def test_decode_step_r0_parity(tiny_lm):
         )(pm, cache_p)
     rel = float(jnp.abs(got - want).max() / jnp.abs(want).max())
     assert rel <= 1e-5, f"decode rel err {rel:.2e}"
+
+
+@pytest.mark.parametrize("mode,block_n", [("structured", 0), ("per_column", 1)])
+def test_fold_lm_params_is_the_xla_reference_at_r_gt_0(tiny_lm, mode, block_n):
+    """fold_lm_params drops the metadata and folds every paired weight: the
+    XLA forward on the folded tree is what the paired kernels compute."""
+    cfg, params = tiny_lm
+    pm, _ = pair_lm_params(params, 0.3, mode=mode, block_n=block_n)
+    folded = fold_lm_params(pm, block_n)
+    assert not has_lm_pairing(folded)
+    assert jax.tree.structure(folded) == jax.tree.structure(params)
+    changed = [
+        name for sub, name in LM_PAIRED_WEIGHTS
+        if not np.allclose(folded["segments"][0][sub][name],
+                           params["segments"][0][sub][name])
+    ]
+    assert changed, "rounding 0.3 must snap some pair magnitudes"
+    base = M.PerfKnobs(q_chunk=16, k_chunk=16, remat="none")
+    knobs = dataclasses.replace(base, gemm="pallas_paired", pair_block_n=block_n)
+    batch = {"tokens": jnp.asarray(
+        np.random.default_rng(7).integers(0, cfg.vocab, (2, 8)), jnp.int32)}
+    want, _, _ = M.lm_forward(cfg, folded, batch, knobs=base)
+    with perf_context(knobs):
+        got, _, _ = jax.jit(lambda p: M.lm_forward(cfg, p, batch, knobs=knobs))(pm)
+    rel = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+    assert rel <= 1e-5, f"{mode}: rel err {rel:.2e}"

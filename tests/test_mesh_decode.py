@@ -6,9 +6,15 @@ the ``sharded_decode`` analysis target (CI's mesh-decode job runs both under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8``).  What *is* real at
 any mesh size: the wiring path (tp_shard_plan → pair_params(shards=…) →
 pairing_axes → paired_shardings_for → pjit), and that it decodes the same
-tokens as the single-host engine.
+tokens as the single-host engine.  One test starts a four-device CPU process
+of its own, so that the kernels' multi-device branch (``kernels.ops.on_mesh``)
+runs on paired weights.
 """
 import dataclasses
+import os
+import subprocess
+import sys
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -17,7 +23,7 @@ import pytest
 from repro.configs import get_smoke_config
 from repro.models import lm as M
 from repro.models.param import unzip
-from repro.parallel.sharding import make_mesh_compat
+from repro.parallel.sharding import auto_mesh
 from repro.serving.engine import ServeEngine
 
 KNOBS = M.PerfKnobs(
@@ -34,7 +40,7 @@ def tiny():
 
 
 def _mesh():
-    return make_mesh_compat((1, jax.device_count()), ("data", "model"))
+    return auto_mesh((1, jax.device_count()), ("data", "model"))
 
 
 def test_mesh_engine_token_parity_r0(tiny):
@@ -82,3 +88,43 @@ def test_mesh_engine_add_release_cycle(tiny):
     assert eng.free_slots() == [0, 1]
     eng.add_request(0, np.arange(1, 4, dtype=np.int32))
     eng.step()
+
+
+#: run in a process with four CPU devices: chip_smoke's mesh phase (the
+#: ``--four-chip`` path) at a rounding that pairs most of the smoke-size
+#: weights, counting the shard_map launches the kernels go through
+_FOUR_DEVICE_MESH = """
+import sys
+import jax
+
+launches = []
+_shard_map = jax.shard_map
+def counting(*a, **k):
+    launches.append(1)
+    return _shard_map(*a, **k)
+jax.shard_map = counting
+
+import chip_smoke
+from repro.configs import get_smoke_config
+from repro.launch.serve import serving_params
+
+assert jax.device_count() == 4, jax.devices()
+cfg = get_smoke_config("qwen2-1.5b")
+chip_smoke.mesh_phase(cfg, serving_params(cfg), 0.25, seed=0, max_seq=64,
+                      request_kw=dict(n=4, prompt_len=(16, 25), new_tokens=4))
+assert launches, "no kernel went through the mesh branch"
+print("launches", len(launches))
+"""
+
+
+def test_four_device_mesh_runs_paired_kernels_in_shard_map():
+    root = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join([str(root / "src"), str(root)]))
+    run = subprocess.run([sys.executable, "-c", _FOUR_DEVICE_MESH], cwd=root,
+                         env=env, capture_output=True, text=True, timeout=600)
+    assert run.returncode == 0, run.stdout[-3000:] + run.stderr[-3000:]
+    out = run.stdout
+    assert "per TP shard" in out and "paired 0 " not in out, out
+    assert "answered 4/4" in out and "launches" in out, out

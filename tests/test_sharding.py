@@ -5,12 +5,12 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
 from repro.parallel.rules import rules_for
-from repro.parallel.sharding import Rules, make_mesh_compat, spec_for_axes
+from repro.parallel.sharding import Rules, auto_mesh, spec_for_axes
 
 
 def _mesh2():
     n = jax.device_count()
-    return make_mesh_compat((1, n), ("data", "model"))
+    return auto_mesh((1, n), ("data", "model"))
 
 
 RULES = Rules({"batch": ("data",), "ff": "model", "vocab": "model",
