@@ -301,9 +301,12 @@ def fused_dense(
 # segment-wide (Pmax, Rmax): padded pair lanes point I == J == 0 (the
 # subtract is exactly zero) and every padded weight row is masked to zero,
 # so padding contracts against nothing — the same zero-lane trick the
-# k-tile padding and the column-blocked packing already use.
+# k-tile padding and the column-blocked packing already use.  The functions
+# that make those live segments run under the ``refold`` named scope, so a
+# profiler trace names the device time of the per-step recomputation.
 
 
+@jax.named_scope("refold")
 def _lm_structured_segments(w2: jax.Array, meta: dict):
     """Live (kmat, w_res) for a structured LM pairing (traced indices)."""
     I, J, Rm = meta["I"], meta["J"], meta["resid"]
@@ -313,6 +316,7 @@ def _lm_structured_segments(w2: jax.Array, meta: dict):
     return kmat, w_res
 
 
+@jax.named_scope("refold")
 def _lm_blocked_weights(w2: jax.Array, n_blocks: int, bn: int) -> jax.Array:
     """(K, N) live weights → block-major (n_blocks, K, bn), zero-padded cols."""
     K, N = w2.shape
@@ -321,6 +325,7 @@ def _lm_blocked_weights(w2: jax.Array, n_blocks: int, bn: int) -> jax.Array:
     return w_p.reshape(K, n_blocks, bn).transpose(1, 0, 2)
 
 
+@jax.named_scope("refold")
 def _take_block_segments(wm_t: jax.Array, meta: dict):
     """Live per-block (kmat, w_res) from block-major (B, K, bn) weights and
     (B, Pmax/Rmax) lane lists."""
@@ -513,6 +518,7 @@ def fused_paired_dense(
 # expert's weight segments only, so nothing is wasted on the batching).
 
 
+@jax.named_scope("refold")
 def _expert_blocked_weights(w: jax.Array, n_blocks: int, bn: int) -> jax.Array:
     """(E, K, F) live expert weights → block-major (E·n_blocks, K, bn),
     zero-padding the short last block of each expert."""

@@ -461,6 +461,15 @@ def attention_block(
     return attn_out_proj(p, out)
 
 
+@jax.named_scope("cache_update")
+def _cache_write(cache: dict, k: jax.Array, v: jax.Array, pos: jax.Array) -> dict:
+    """The new token's K and V (``(B, 1, KH, hd)``) written into row ``pos``
+    of each slot's cache."""
+    bidx = jnp.arange(k.shape[0])
+    return {"k": cache["k"].at[bidx, pos].set(k[:, 0].astype(cache["k"].dtype)),
+            "v": cache["v"].at[bidx, pos].set(v[:, 0].astype(cache["v"].dtype))}
+
+
 def attention_decode_block(
     cfg: ModelConfig,
     p: dict,
@@ -477,13 +486,10 @@ def attention_decode_block(
     apol = kops.current_attn_policy()
     if apol is None or x.shape[1] != 1:
         q, k, v = _qkv(cfg, p, x, pos[:, None])
-        B = x.shape[0]
-        bidx = jnp.arange(B)
-        k_cache = cache["k"].at[bidx, pos].set(k[:, 0].astype(cache["k"].dtype))
-        v_cache = cache["v"].at[bidx, pos].set(v[:, 0].astype(cache["v"].dtype))
-        out = decode_attention(q, k_cache, v_cache, pos, window=window, n_sink=n_sink)
+        new = _cache_write(cache, k, v, pos)
+        out = decode_attention(q, new["k"], new["v"], pos, window=window, n_sink=n_sink)
         y = attn_out_proj(p, out, residual=residual)
-        return y, {"k": k_cache, "v": v_cache}
+        return y, new
 
     # fused decode path (PerfKnobs(attn="pallas_fused")): one subtractor
     # launch projects q|k|v together when the blocked metadata concatenates,
@@ -496,21 +502,18 @@ def attention_decode_block(
         q, k, v = _qkv(cfg, p, x, pos[:, None])
     else:
         q, k, v = _qkv_post(cfg, p, *qkv, pos[:, None])
-    B = x.shape[0]
-    bidx = jnp.arange(B)
-    k_cache = cache["k"].at[bidx, pos].set(k[:, 0].astype(cache["k"].dtype))
-    v_cache = cache["v"].at[bidx, pos].set(v[:, 0].astype(cache["v"].dtype))
+    new = _cache_write(cache, k, v, pos)
     wo = p["wo"].astype(x.dtype)
     H, hd, d = wo.shape
     meta = p.get("wo_pairing") if ppol is not None else None
     y = kops.fused_attn_decode(
-        q, k_cache, v_cache, pos, wo.reshape(H * hd, d), meta,
+        q, new["k"], new["v"], pos, wo.reshape(H * hd, d), meta,
         residual=residual,
         pair_block_n=ppol.pair_block_n if ppol is not None else 0,
         window=window, n_sink=n_sink,
         k_chunk=apol.k_chunk, interpret=apol.interpret,
     )
-    return y, {"k": k_cache, "v": v_cache}
+    return y, new
 
 
 # ---------------------------------------------------------------------------

@@ -204,11 +204,13 @@ def _sinusoid(positions: jax.Array, d: int) -> jax.Array:
     return jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1)
 
 
+@jax.named_scope("embed")
 def embed_tokens(cfg: ModelConfig, params: dict, tokens: jax.Array, cdt) -> jax.Array:
     h = jnp.take(params["embed"], tokens, axis=0).astype(cdt)
     return h * jnp.asarray(math.sqrt(cfg.d_model), cdt) if cfg.tie_embeddings else h
 
 
+@jax.named_scope("head")
 def lm_logits(cfg: ModelConfig, params: dict, h: jax.Array) -> jax.Array:
     h = L.apply_norm(params["final_norm"], h)
     if cfg.tie_embeddings:
@@ -224,6 +226,24 @@ def lm_logits(cfg: ModelConfig, params: dict, h: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 # decoder layer forward (training / prefill)
 # ---------------------------------------------------------------------------
+
+
+def _mixer_scope(kind: str) -> str:
+    """Named scope of a layer's token mixer in a profiler trace: ``attn``
+    (with any cross attention or SSM beside it), or ``ssm`` alone."""
+    return "ssm" if kind == "ssm" else "attn"
+
+
+@jax.named_scope("mlp")
+def _ffn(cfg: ModelConfig, p: dict, h: jax.Array):
+    """The feed-forward sublayer (gated MLP or MoE) with its skip connection
+    (fused into the down-projection under the paired policy); returns
+    ``(h, aux)``, the MoE balance loss or 0."""
+    x2 = L.apply_norm(p["ln2"], h)
+    if "moe" in p:
+        y2, aux = L.moe_block(cfg, p["moe"], x2)
+        return h + y2, aux
+    return L.mlp_block(cfg, p["mlp"], x2, residual=h), jnp.float32(0.0)
 
 
 def _window_for(cfg: ModelConfig, kind: str) -> int:
@@ -250,50 +270,46 @@ def layer_fwd(
     window = _window_for(cfg, kind)
     n_sink = cfg.meta_tokens
 
-    x = L.apply_norm(p["ln1"], h)
-    if kind == "ssm":
-        y, ssm_cache = _ssm_with_cache(cfg, p["mamba"], x, collect_cache)
-        h = h + y
-        cache = ssm_cache
-    elif kind in ("hybrid_full", "hybrid_swa"):
-        attn_cache = None
-        if cfg.mla:
-            a = L.mla_block(cfg, p["attn"], x, positions, q_chunk=knobs.q_chunk, k_chunk=knobs.k_chunk)
-        else:
-            a, attn_cache = _attn_with_cache(
-                cfg, p["attn"], x, positions, window, n_sink, knobs, collect_cache
-            )
-        m, ssm_cache = _ssm_with_cache(cfg, p["mamba"], x, collect_cache)
-        y = 0.5 * (L.apply_norm(p["ln_attn_out"], a) + L.apply_norm(p["ln_ssm_out"], m))
-        h = h + y
-        if collect_cache:
-            cache = {**(attn_cache or {}), **(ssm_cache or {})}
-    else:  # dense / moe / encdec — attention first
-        if cfg.mla:
-            if collect_cache:
-                a, cache = _mla_with_cache(cfg, p["attn"], x, positions, knobs)
-            else:
+    with jax.named_scope(_mixer_scope(kind)):
+        x = L.apply_norm(p["ln1"], h)
+        if kind == "ssm":
+            y, ssm_cache = _ssm_with_cache(cfg, p["mamba"], x, collect_cache)
+            h = h + y
+            cache = ssm_cache
+        elif kind in ("hybrid_full", "hybrid_swa"):
+            attn_cache = None
+            if cfg.mla:
                 a = L.mla_block(cfg, p["attn"], x, positions, q_chunk=knobs.q_chunk, k_chunk=knobs.k_chunk)
-            h = h + a
-        else:
-            # the skip connection rides the out-projection (fused into the
-            # paired kernel's epilogue under gemm="pallas_paired")
-            h, cache = _attn_with_cache(
-                cfg, p["attn"], x, positions, window, n_sink, knobs,
-                collect_cache, residual=h,
-            )
-        if kind == "encdec":
-            xq = L.apply_norm(p["lnx"], h)
-            # skip connection rides the paired out-projection epilogue
-            h = _cross_attention(cfg, p["xattn"], xq, enc_out, knobs, residual=h)
+            else:
+                a, attn_cache = _attn_with_cache(
+                    cfg, p["attn"], x, positions, window, n_sink, knobs, collect_cache
+                )
+            m, ssm_cache = _ssm_with_cache(cfg, p["mamba"], x, collect_cache)
+            y = 0.5 * (L.apply_norm(p["ln_attn_out"], a) + L.apply_norm(p["ln_ssm_out"], m))
+            h = h + y
+            if collect_cache:
+                cache = {**(attn_cache or {}), **(ssm_cache or {})}
+        else:  # dense / moe / encdec — attention first
+            if cfg.mla:
+                if collect_cache:
+                    a, cache = _mla_with_cache(cfg, p["attn"], x, positions, knobs)
+                else:
+                    a = L.mla_block(cfg, p["attn"], x, positions, q_chunk=knobs.q_chunk, k_chunk=knobs.k_chunk)
+                h = h + a
+            else:
+                # the skip connection rides the out-projection (fused into the
+                # paired kernel's epilogue under gemm="pallas_paired")
+                h, cache = _attn_with_cache(
+                    cfg, p["attn"], x, positions, window, n_sink, knobs,
+                    collect_cache, residual=h,
+                )
+            if kind == "encdec":
+                xq = L.apply_norm(p["lnx"], h)
+                # skip connection rides the paired out-projection epilogue
+                h = _cross_attention(cfg, p["xattn"], xq, enc_out, knobs, residual=h)
 
     if "mlp" in p or "moe" in p:
-        x2 = L.apply_norm(p["ln2"], h)
-        if "moe" in p:
-            y2, aux = L.moe_block(cfg, p["moe"], x2)
-            h = h + y2
-        else:
-            h = L.mlp_block(cfg, p["mlp"], x2, residual=h)
+        h, aux = _ffn(cfg, p, h)
 
     h = constrain(h, "batch", "seq", None)
     return h, aux, cache
@@ -538,9 +554,10 @@ def lm_forward(
             )
             return (h2, aux + aux2), cache
 
-        (h, aux_total), seg_cache = jax.lax.scan(
-            _remat(body, knobs), (h, aux_total), seg_params
-        )
+        with jax.named_scope("layer_scan"):
+            (h, aux_total), seg_cache = jax.lax.scan(
+                _remat(body, knobs), (h, aux_total), seg_params
+            )
         caches.append(seg_cache)
 
     if offset:
@@ -732,59 +749,55 @@ def layer_decode(
 ):
     window = _window_for(cfg, kind)
     n_sink = cfg.meta_tokens
-    x = L.apply_norm(p["ln1"], h)
-    c_out = dict(c)
-    if kind == "ssm":
-        y, ssm_c = L.ssm_decode_block(cfg, p["mamba"], x, c, pos)
-        h = h + y
-        c_out.update(ssm_c)
-    elif kind in ("hybrid_full", "hybrid_swa"):
-        a, attn_c = L.attention_decode_block(
-            cfg, p["attn"], x, {"k": c["k"], "v": c["v"]}, pos,
-            window=window, n_sink=n_sink,
-        )
-        m, ssm_c = L.ssm_decode_block(
-            cfg, p["mamba"], x,
-            {k: c[k] for k in ("h", "conv_x", "conv_B", "conv_C")}, pos,
-        )
-        y = 0.5 * (L.apply_norm(p["ln_attn_out"], a) + L.apply_norm(p["ln_ssm_out"], m))
-        h = h + y
-        c_out.update(attn_c)
-        c_out.update(ssm_c)
-    else:
-        if cfg.mla:
-            a, mla_c = L.mla_decode_block(
-                cfg, p["attn"], x, {"c_kv": c["c_kv"], "k_rope": c["k_rope"]}, pos
-            )
-            c_out.update(mla_c)
-            h = h + a
-        else:
-            # skip connection fused into the out-projection epilogue
-            h, attn_c = L.attention_decode_block(
+    with jax.named_scope(_mixer_scope(kind)):
+        x = L.apply_norm(p["ln1"], h)
+        c_out = dict(c)
+        if kind == "ssm":
+            y, ssm_c = L.ssm_decode_block(cfg, p["mamba"], x, c, pos)
+            h = h + y
+            c_out.update(ssm_c)
+        elif kind in ("hybrid_full", "hybrid_swa"):
+            a, attn_c = L.attention_decode_block(
                 cfg, p["attn"], x, {"k": c["k"], "v": c["v"]}, pos,
-                window=window, n_sink=n_sink, residual=h,
+                window=window, n_sink=n_sink,
             )
+            m, ssm_c = L.ssm_decode_block(
+                cfg, p["mamba"], x,
+                {k: c[k] for k in ("h", "conv_x", "conv_B", "conv_C")}, pos,
+            )
+            y = 0.5 * (L.apply_norm(p["ln_attn_out"], a) + L.apply_norm(p["ln_ssm_out"], m))
+            h = h + y
             c_out.update(attn_c)
-        if kind == "encdec":
-            xq = L.apply_norm(p["lnx"], h)
-            # cross attention against the precomputed encoder K/V; the wq/wo
-            # projections route through layers.dense so the xattn pairing
-            # metadata reaches the subtractor kernel, with the skip
-            # connection fused into the out-projection epilogue
-            q = _xattn_q(p["xattn"], xq)
-            out = L.decode_attention(
-                q, c["xk"], c["xv"],
-                jnp.full((h.shape[0],), c["xk"].shape[1] - 1, jnp.int32),
-            )
-            h = L.attn_out_proj(p["xattn"], out, residual=h)
+            c_out.update(ssm_c)
+        else:
+            if cfg.mla:
+                a, mla_c = L.mla_decode_block(
+                    cfg, p["attn"], x, {"c_kv": c["c_kv"], "k_rope": c["k_rope"]}, pos
+                )
+                c_out.update(mla_c)
+                h = h + a
+            else:
+                # skip connection fused into the out-projection epilogue
+                h, attn_c = L.attention_decode_block(
+                    cfg, p["attn"], x, {"k": c["k"], "v": c["v"]}, pos,
+                    window=window, n_sink=n_sink, residual=h,
+                )
+                c_out.update(attn_c)
+            if kind == "encdec":
+                xq = L.apply_norm(p["lnx"], h)
+                # cross attention against the precomputed encoder K/V; the wq/wo
+                # projections route through layers.dense so the xattn pairing
+                # metadata reaches the subtractor kernel, with the skip
+                # connection fused into the out-projection epilogue
+                q = _xattn_q(p["xattn"], xq)
+                out = L.decode_attention(
+                    q, c["xk"], c["xv"],
+                    jnp.full((h.shape[0],), c["xk"].shape[1] - 1, jnp.int32),
+                )
+                h = L.attn_out_proj(p["xattn"], out, residual=h)
 
     if "mlp" in p or "moe" in p:
-        x2 = L.apply_norm(p["ln2"], h)
-        if "moe" in p:
-            y2, _ = L.moe_block(cfg, p["moe"], x2)
-            h = h + y2
-        else:
-            h = L.mlp_block(cfg, p["mlp"], x2, residual=h)
+        h, _ = _ffn(cfg, p, h)
     return h, c_out
 
 
@@ -812,7 +825,10 @@ def decode_step(
             h2, c2 = layer_decode(cfg, _kind, lp, c, h, pos_abs)
             return h2, c2
 
-        h, seg_cache_new = jax.lax.scan(body, h, (seg_params, seg_cache))
+        # the scan's own ops (each layer's weights and cache sliced out of
+        # the stacks, the new cache stacked back) carry only this scope
+        with jax.named_scope("layer_scan"):
+            h, seg_cache_new = jax.lax.scan(body, h, (seg_params, seg_cache))
         new_segs.append(seg_cache_new)
 
     logits = lm_logits(cfg, params, h)

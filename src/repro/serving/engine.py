@@ -22,6 +22,7 @@ from repro.configs.base import ModelConfig
 from repro.kernels.ops import perf_context
 from repro.models import lm as M
 from repro.models.param import unzip
+from repro.serving.trace import span
 
 
 class CapacityError(ValueError):
@@ -36,6 +37,22 @@ class CapacityError(ValueError):
 #: token emitted for slots that are not active — callers must never treat it
 #: as model output (vocab ids are non-negative, so -1 can't collide)
 INACTIVE_TOKEN = -1
+
+
+@dataclasses.dataclass
+class EngineCounters:
+    """Running totals of an engine's work, for operators.  The engine's
+    profiler spans (``repro.serving.trace``) carry the same values as args."""
+
+    steps: int = 0
+    admissions: int = 0
+    forced_tokens: int = 0
+    releases: int = 0
+    #: every byte the engine copied to the host (the arrays' ``nbytes``)
+    d2h_bytes: int = 0
+    #: runs of the Python bodies of the jitted decode and prefill steps: each
+    #: is a trace, so growth after warm-up is a recompile
+    traces: int = 0
 
 
 @dataclasses.dataclass
@@ -70,6 +87,9 @@ class ServeEngine:
                 "cell decodes against a sequence-sharded cache, and the fused "
                 "decode-attention kernel has no cross-shard softmax yet — "
                 "the mesh path keeps the dense decode attention")
+        self.counters = EngineCounters()
+        # admission ordinal of each slot's request (-1: never admitted)
+        self.slot_req = [-1] * self.batch_size
         cache_tree = M.init_cache(self.cfg, self.batch_size, self.max_seq)
         self.cache, _ = unzip(cache_tree)
         self.pos = jnp.zeros((self.batch_size,), jnp.int32)
@@ -134,17 +154,20 @@ class ServeEngine:
         # pairing-mode spectrum point the conv artifacts use), and
         # knobs.tile_cache points tile selection at persisted measured
         # winners (the policies are consulted at trace time, so they must
-        # wrap the function body, not the jit call).
-        def decode_fn(p, c, t, pos):
+        # wrap the function body, not the jit call).  Their names are the
+        # names of the programs in a profiler trace (``jit_serve_decode``).
+        def serve_decode(p, c, t, pos):
+            self.counters.traces += 1
             with perf_context(self.knobs):
                 return M.decode_step(self.cfg, p, c, t, pos)
 
-        def prefill_fn(p, b):
+        def serve_prefill(p, b):
+            self.counters.traces += 1
             with perf_context(self.knobs):
                 return M.prefill(self.cfg, p, b, knobs=self.knobs)
 
-        self._decode = jax.jit(decode_fn)
-        self._prefill = jax.jit(prefill_fn)
+        self._decode = jax.jit(serve_decode)
+        self._prefill = jax.jit(serve_prefill)
 
     # -- request management -------------------------------------------------
     def add_request(self, slot: int, prompt: np.ndarray, extras: dict | None = None):
@@ -172,10 +195,6 @@ class ServeEngine:
             raise CapacityError(
                 f"prompt length {plen} leaves no decode room in "
                 f"max_seq={self.max_seq} (need plen < max_seq)")
-        batch = {"tokens": jnp.asarray(prompt[None, :], jnp.int32)}
-        batch.update(extras or {})
-        last_logits, cache = self._prefill(self.params, batch)
-
         # splice this request's prefill cache into the engine cache at `slot`
         def splice(dst_seg, src_seg):
             out = {}
@@ -192,16 +211,31 @@ class ServeEngine:
                     out[k] = dst.at[:, slot, :L].set(src[:, 0])
             return out
 
-        self.cache = {
-            "segments": [
-                splice(d, s)
-                for d, s in zip(self.cache["segments"], cache["segments"], strict=True)
-            ]
-        }
-        self.pos = self.pos.at[slot].set(plen)
-        next_tok = int(jnp.argmax(last_logits[0, -1, : self.cfg.vocab]))
-        self.tokens = self.tokens.at[slot, 0].set(next_tok)
-        self.active[slot] = True
+        req = self.counters.admissions
+        with span("engine.admit", slot=slot, req=req, prompt_tokens=plen):
+            batch = {"tokens": jnp.asarray(prompt[None, :], jnp.int32)}
+            batch.update(extras or {})
+            with span("engine.admit.prefill") as sp:
+                last_logits, cache = self._prefill(self.params, batch)
+                sp.set_metadata(traces=self.counters.traces)
+
+            with span("engine.admit.splice"):
+                self.cache = {
+                    "segments": [
+                        splice(d, s)
+                        for d, s in zip(self.cache["segments"], cache["segments"], strict=True)
+                    ]
+                }
+                self.pos = self.pos.at[slot].set(plen)
+            with span("engine.admit.first_token") as sp:
+                first = jnp.argmax(last_logits[0, -1, : self.cfg.vocab])
+                next_tok = int(first)
+                sp.set_metadata(bytes=first.nbytes)
+            self.counters.d2h_bytes += first.nbytes
+            self.tokens = self.tokens.at[slot, 0].set(next_tok)
+            self.active[slot] = True
+            self.slot_req[slot] = req
+            self.counters.admissions += 1
         return next_tok
 
     def prefill_logits(self, prompt: np.ndarray) -> np.ndarray:
@@ -210,7 +244,9 @@ class ServeEngine:
         parity probe on the engine's own compiled step)."""
         batch = {"tokens": jnp.asarray(prompt[None, :], jnp.int32)}
         last_logits, _ = self._prefill(self.params, batch)
-        return np.asarray(last_logits[0, -1, : self.cfg.vocab])
+        out = np.asarray(last_logits[0, -1, : self.cfg.vocab])
+        self.counters.d2h_bytes += out.nbytes
+        return out
 
     def step(self, sample: Callable | None = None) -> np.ndarray:
         """One decode step for every active slot. Returns (batch,) next tokens.
@@ -220,26 +256,44 @@ class ServeEngine:
         when any *active* slot has no cache row left (``pos >= max_seq``)
         instead of letting the scatter clamp into the last row.
         """
-        over = self.active & (np.asarray(self.pos) >= self.max_seq)
-        if over.any():
-            raise CapacityError(
-                f"slot(s) {np.flatnonzero(over).tolist()} at pos "
-                f"{np.asarray(self.pos)[over].tolist()} have no cache rows "
-                f"left (max_seq={self.max_seq}) — evict or raise max_seq")
-        logits, self.cache = self._decode(self.params, self.cache, self.tokens, self.pos)
-        logits = logits[:, 0, : self.cfg.vocab]
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32) if sample is None else sample(logits)
-        self.pos = self.pos + jnp.asarray(self.active, jnp.int32)
-        self.tokens = nxt[:, None]
-        self.last_logits = np.asarray(logits)
-        return np.where(self.active, np.asarray(nxt), INACTIVE_TOKEN)
+        c = self.counters
+        with span("engine.step", step=c.steps, active=int(self.active.sum())) as st:
+            with span("engine.step.tokens_to_host", bytes=self.pos.nbytes):
+                pos = np.asarray(self.pos)
+            moved = pos.nbytes
+            over = self.active & (pos >= self.max_seq)
+            if over.any():
+                raise CapacityError(
+                    f"slot(s) {np.flatnonzero(over).tolist()} at pos "
+                    f"{pos[over].tolist()} have no cache rows "
+                    f"left (max_seq={self.max_seq}) — evict or raise max_seq")
+            with span("engine.step.dispatch") as sp:
+                logits, self.cache = self._decode(self.params, self.cache, self.tokens, self.pos)
+                sp.set_metadata(traces=c.traces)
+            with span("engine.step.sample"):
+                logits = logits[:, 0, : self.cfg.vocab]
+                nxt = (jnp.argmax(logits, axis=-1).astype(jnp.int32) if sample is None
+                       else sample(logits))
+                self.pos = self.pos + jnp.asarray(self.active, jnp.int32)
+                self.tokens = nxt[:, None]
+            with span("engine.step.logits_to_host", bytes=logits.nbytes):
+                self.last_logits = np.asarray(logits)
+            with span("engine.step.tokens_to_host", bytes=nxt.nbytes):
+                nxt = np.asarray(nxt)
+            moved += self.last_logits.nbytes + nxt.nbytes
+            st.set_metadata(d2h_bytes=moved)
+        c.steps += 1
+        c.d2h_bytes += moved
+        return np.where(self.active, nxt, INACTIVE_TOKEN)
 
     def force_token(self, slot: int, token: int) -> None:
         """Override the next input token for one slot (chunked prefill:
         the front end feeds the unprefilled tail of a long prompt through
         the shared decode steps, one token per step, so other slots keep
         decoding instead of stalling behind a monolithic prefill)."""
-        self.tokens = self.tokens.at[slot, 0].set(int(token))
+        with span("engine.force", slot=slot, req=self.slot_req[slot]):
+            self.tokens = self.tokens.at[slot, 0].set(int(token))
+        self.counters.forced_tokens += 1
 
     def release_slot(self, slot: int, *, scrub: bool = True) -> None:
         """Evict a slot: mark it free and (by default) scrub its cache rows.
@@ -251,16 +305,19 @@ class ServeEngine:
         if not 0 <= slot < self.batch_size:
             raise CapacityError(
                 f"slot {slot} out of range for batch_size={self.batch_size}")
-        self.active[slot] = False
-        self.pos = self.pos.at[slot].set(0)
-        self.tokens = self.tokens.at[slot, 0].set(0)
-        if scrub:
-            self.cache = {
-                "segments": [
-                    {k: v.at[:, slot].set(0) for k, v in seg.items()}
-                    for seg in self.cache["segments"]
-                ]
-            }
+        with span("engine.release", slot=slot, req=self.slot_req[slot]):
+            self.active[slot] = False
+            self.pos = self.pos.at[slot].set(0)
+            self.tokens = self.tokens.at[slot, 0].set(0)
+            if scrub:
+                with span("engine.release.scrub"):
+                    self.cache = {
+                        "segments": [
+                            {k: v.at[:, slot].set(0) for k, v in seg.items()}
+                            for seg in self.cache["segments"]
+                        ]
+                    }
+        self.counters.releases += 1
 
     def quarantine_slot(self, slot: int) -> None:
         """Pull a slot out of service: evict + scrub + refuse admission until
