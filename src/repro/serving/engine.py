@@ -92,8 +92,15 @@ class ServeEngine:
         self.slot_req = [-1] * self.batch_size
         cache_tree = M.init_cache(self.cfg, self.batch_size, self.max_seq)
         self.cache, _ = unzip(cache_tree)
-        self.pos = jnp.zeros((self.batch_size,), jnp.int32)
-        self.tokens = jnp.zeros((self.batch_size, 1), jnp.int32)
+        # each slot's position and next input token live on the host, which
+        # reads every step's tokens anyway: admission, forced tokens and
+        # release are numpy writes, and the decode dispatch carries both to
+        # the device as its arguments
+        self.pos = np.zeros((self.batch_size,), np.int32)
+        self.tokens = np.zeros((self.batch_size, 1), np.int32)
+        # force_token calls since the last step (the ``forced`` arg of the
+        # ``engine.step`` span)
+        self._forced = 0
         self.active = np.zeros((self.batch_size,), bool)
         # slots a numeric watchdog pulled out of service (see serving.guards):
         # quarantined slots refuse admission until clear_quarantine() runs
@@ -226,13 +233,13 @@ class ServeEngine:
                         for d, s in zip(self.cache["segments"], cache["segments"], strict=True)
                     ]
                 }
-                self.pos = self.pos.at[slot].set(plen)
+                self.pos[slot] = plen
             with span("engine.admit.first_token") as sp:
                 first = jnp.argmax(last_logits[0, -1, : self.cfg.vocab])
                 next_tok = int(first)
                 sp.set_metadata(bytes=first.nbytes)
             self.counters.d2h_bytes += first.nbytes
-            self.tokens = self.tokens.at[slot, 0].set(next_tok)
+            self.tokens[slot, 0] = next_tok
             self.active[slot] = True
             self.slot_req[slot] = req
             self.counters.admissions += 1
@@ -255,32 +262,39 @@ class ServeEngine:
         sequences stop producing model output.  Raises :class:`CapacityError`
         when any *active* slot has no cache row left (``pos >= max_seq``)
         instead of letting the scatter clamp into the last row.
+
+        The slots' input tokens and positions are host arrays: the capacity
+        check reads no device value, the decode dispatch takes a snapshot of
+        both as its arguments, and the step's one host read of its tokens
+        becomes the next step's inputs.
         """
         c = self.counters
-        with span("engine.step", step=c.steps, active=int(self.active.sum())) as st:
-            with span("engine.step.tokens_to_host", bytes=self.pos.nbytes):
-                pos = np.asarray(self.pos)
-            moved = pos.nbytes
-            over = self.active & (pos >= self.max_seq)
+        with span("engine.step", step=c.steps, active=int(self.active.sum()),
+                  forced=self._forced) as st:
+            over = self.active & (self.pos >= self.max_seq)
             if over.any():
                 raise CapacityError(
                     f"slot(s) {np.flatnonzero(over).tolist()} at pos "
-                    f"{pos[over].tolist()} have no cache rows "
+                    f"{self.pos[over].tolist()} have no cache rows "
                     f"left (max_seq={self.max_seq}) — evict or raise max_seq")
+            self._forced = 0
             with span("engine.step.dispatch") as sp:
-                logits, self.cache = self._decode(self.params, self.cache, self.tokens, self.pos)
+                # copies: a backend may alias a numpy argument's buffer, and
+                # both arrays are written again before the step has run
+                logits, self.cache = self._decode(
+                    self.params, self.cache, self.tokens.copy(), self.pos.copy())
                 sp.set_metadata(traces=c.traces)
             with span("engine.step.sample"):
                 logits = logits[:, 0, : self.cfg.vocab]
                 nxt = (jnp.argmax(logits, axis=-1).astype(jnp.int32) if sample is None
                        else sample(logits))
-                self.pos = self.pos + jnp.asarray(self.active, jnp.int32)
-                self.tokens = nxt[:, None]
             with span("engine.step.logits_to_host", bytes=logits.nbytes):
                 self.last_logits = np.asarray(logits)
             with span("engine.step.tokens_to_host", bytes=nxt.nbytes):
                 nxt = np.asarray(nxt)
-            moved += self.last_logits.nbytes + nxt.nbytes
+            self.tokens = nxt.astype(np.int32).reshape(self.batch_size, 1)
+            self.pos += self.active
+            moved = self.last_logits.nbytes + nxt.nbytes
             st.set_metadata(d2h_bytes=moved)
         c.steps += 1
         c.d2h_bytes += moved
@@ -290,10 +304,14 @@ class ServeEngine:
         """Override the next input token for one slot (chunked prefill:
         the front end feeds the unprefilled tail of a long prompt through
         the shared decode steps, one token per step, so other slots keep
-        decoding instead of stalling behind a monolithic prefill)."""
+        decoding instead of stalling behind a monolithic prefill).
+
+        A host write into the slots' input tokens: no device op, no
+        transfer; the next :meth:`step` carries it to the device."""
         with span("engine.force", slot=slot, req=self.slot_req[slot]):
-            self.tokens = self.tokens.at[slot, 0].set(int(token))
+            self.tokens[slot, 0] = token
         self.counters.forced_tokens += 1
+        self._forced += 1
 
     def release_slot(self, slot: int, *, scrub: bool = True) -> None:
         """Evict a slot: mark it free and (by default) scrub its cache rows.
@@ -307,8 +325,8 @@ class ServeEngine:
                 f"slot {slot} out of range for batch_size={self.batch_size}")
         with span("engine.release", slot=slot, req=self.slot_req[slot]):
             self.active[slot] = False
-            self.pos = self.pos.at[slot].set(0)
-            self.tokens = self.tokens.at[slot, 0].set(0)
+            self.pos[slot] = 0
+            self.tokens[slot, 0] = 0
             if scrub:
                 with span("engine.release.scrub"):
                     self.cache = {

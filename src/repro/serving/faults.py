@@ -156,7 +156,7 @@ def poison_slot_cache(engine, slot: int) -> None:
     """NaN one slot's cache rows in place: attended K/V positions (below the
     slot's pos, so the poison provably reaches the next step's logits), full
     SSM/conv state, and cross-attention frames."""
-    upto = max(1, int(np.asarray(engine.pos)[slot]))
+    upto = max(1, int(engine.pos[slot]))
     segs = []
     for seg in engine.cache["segments"]:
         out = {}

@@ -11,15 +11,18 @@ The engine's spans (``ServeEngine``), parents before their children:
 
 * ``engine.admit`` (``slot``, ``req``, ``prompt_tokens``): all of an
   admission, with ``engine.admit.prefill`` (the prefill dispatch, ``traces``),
-  ``engine.admit.splice`` (the cache splice and the position update) and
-  ``engine.admit.first_token`` (the host read of the first token, ``bytes``);
-* ``engine.step`` (``step``, ``active``, ``d2h_bytes``): one decode step, with
-  ``engine.step.dispatch`` (the decode dispatch, ``traces``),
-  ``engine.step.sample`` (slice, argmax, position and token updates),
-  ``engine.step.logits_to_host`` and ``engine.step.tokens_to_host`` (each
-  with its ``bytes``; the latter twice, for the position read of the capacity
-  check and for the tokens);
-* ``engine.force`` (``slot``, ``req``): one forced prompt token;
+  ``engine.admit.splice`` (the cache splice and the host write of the
+  position) and ``engine.admit.first_token`` (the host read of the first
+  token, ``bytes``);
+* ``engine.step`` (``step``, ``active``, ``forced``, ``d2h_bytes``): one
+  decode step, ``forced`` the ``engine.force`` calls since the previous step;
+  with ``engine.step.dispatch`` (the decode dispatch, which carries the
+  slots' host-held tokens and positions to the device, ``traces``),
+  ``engine.step.sample`` (slice and argmax), ``engine.step.logits_to_host``
+  and ``engine.step.tokens_to_host`` (each with its ``bytes``; the tokens are
+  read once a step, and become the next step's inputs on the host);
+* ``engine.force`` (``slot``, ``req``): one forced prompt token, a host
+  write;
 * ``engine.release`` (``slot``, ``req``), with ``engine.release.scrub``.
 
 ``req`` is the admission ordinal, so a request's admission, its forced

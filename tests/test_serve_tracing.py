@@ -36,7 +36,7 @@ ARGS = {
     "engine.admit": {"slot", "req", "prompt_tokens"},
     "engine.admit.prefill": {"traces"},
     "engine.admit.first_token": {"bytes"},
-    "engine.step": {"step", "active", "d2h_bytes"},
+    "engine.step": {"step", "active", "forced", "d2h_bytes"},
     "engine.step.dispatch": {"traces"},
     "engine.step.logits_to_host": {"bytes"},
     "engine.step.tokens_to_host": {"bytes"},
@@ -119,8 +119,8 @@ def test_every_span_is_emitted_with_its_parent_and_args(traced):
     steps = [s for s in traced if s[0] == "engine.step"]
     assert len(steps) == 2 and all(s[3]["active"] == BATCH for s in steps)
     assert steps[1][3]["step"] == steps[0][3]["step"] + 1
-    # the capacity check's position read and the tokens: two copies a step
-    assert sum(s[0] == "engine.step.tokens_to_host" for s in traced) == 4
+    # the tokens, copied once a step (the positions stay on the host)
+    assert sum(s[0] == "engine.step.tokens_to_host" for s in traced) == 2
 
 
 def test_req_ties_an_admission_to_its_forced_tokens_and_release(traced):
@@ -150,8 +150,22 @@ def test_counters_count_steps_and_host_bytes():
     eng.force_token(1, 3)
     eng.release_slot(0)
     vocab = eng.cfg.vocab
-    assert c.d2h_bytes - before == 3 * (BATCH * vocab * 4 + 2 * BATCH * 4)
+    assert c.d2h_bytes - before == 3 * (BATCH * vocab * 4 + BATCH * 4)
     assert (c.steps, c.forced_tokens, c.releases) == (3, 1, 1)
+
+
+def test_step_counts_the_tokens_forced_since_the_last_step(engine, tmp_path):
+    eng = engine
+    eng.step()  # takes in whatever an earlier test forced
+    with jax.profiler.trace(str(tmp_path)):
+        eng.force_token(0, 3)
+        eng.force_token(3, 4)
+        eng.step()
+        eng.step()
+        eng.force_token(1, 5)
+        eng.step()
+    spans = host_spans(sorted(glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True))[-1])
+    assert [s[3]["forced"] for s in spans if s[0] == "engine.step"] == [2, 0, 1]
 
 
 def test_traces_do_not_grow_over_repeated_steps(engine):
