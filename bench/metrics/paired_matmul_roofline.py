@@ -3,41 +3,35 @@ events of the device trace): the least time of every call in the traced
 window, over the device time of those events.
 
 A call's work is ``y = (x[I] - x[J]) K + x[R] W_R`` at the call's own
-shapes (``bench.work.paired_gemm``), with each layer's own pair and residual
-counts; padding lanes are not work.  A decode step calls the kernel for
-every paired weight of every layer but the out-projection, which the fused
-decode-attention kernel applies when ``attn`` is ``pallas_fused``; a prefill
-calls it for all seven.  Returns nothing when the trace holds a different
-number of such events than the window made calls: then the events are not
-these calls.
+shapes (``bench.work.paired_gemm``; each weight's N and fused residual from
+the architecture's ``gemms``, ``run.reference``), with each layer's own
+pair and residual counts; padding lanes are not work.  A decode step calls
+the kernel for every paired weight of every layer but the out-projection,
+which the fused decode-attention kernel applies when ``attn`` is
+``pallas_fused``; a prefill calls it for all seven.  Returns nothing when
+the trace holds a different number of such events than the window made
+calls: then the events are not these calls.
 """
 import sys
 
-from bench import reference, work
+from bench import work
 from bench.peaks import least_time_s, peaks_for
 
 KERNEL = "paired_matmul"
-RESIDUAL = ("wo", "w_down")  # the sublayer skip connection rides these
-
-
-def gemm_shape(name, s):
-    d, f, q, kv = s["d"], s["f"], s["H"] * s["hd"], s["KH"] * s["hd"]
-    return {"wq": (d, q), "wk": (d, kv), "wv": (d, kv), "wo": (q, d),
-            "w_gate": (d, f), "w_up": (d, f), "w_down": (f, d)}[name]
 
 
 def calls(run, M, names):
     """(least seconds, call count) of one launch of each of ``names`` per layer."""
-    s = reference.sizes(run.config)
+    gemms = run.reference.gemms(run.config)
     peaks = peaks_for(run.device_kind)
     total, n = 0.0, 0
     for name in names:
-        _, N = gemm_shape(name, s)
+        _, N, residual = gemms[name]
         P, R = run.pairs[name]
         for p, r in zip(P, R, strict=True):
             if p == 0:
                 continue  # no pair lanes: the kernel is dense_matmul
-            w = work.paired_gemm(M, N, int(p), int(r), residual=name in RESIDUAL)
+            w = work.paired_gemm(M, N, int(p), int(r), residual=residual)
             total += least_time_s(w.flops, w.bytes, peaks)
             n += 1
     return total, n

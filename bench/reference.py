@@ -1,28 +1,23 @@
-"""Plain float32 reference of the served dense GQA decoder.
+"""What every plain reference of the benchmark shares.
 
-It imports nothing of the program and takes nothing the program made: the
-weights are drawn again from the seed (``bench.weights``), and the
-subtractor pairing is worked out again by its own code from the rule the
-program implements (structured pairing: one row pairing shared by every
-column of a weight, ``y = (x[I] - x[J]) K + x[R] W_R`` with
-``K = (W[I] - W[J]) / 2``).  The forward then applies the effective
-weights ``W[I] = +K, W[J] = -K, W[R] = W[R]`` in float32 under
-``default_matmul_precision("highest")``.
+A configuration file names its architecture, and ``bench/references/
+<architecture>.py`` holds that architecture's float32 forward
+(``bench/references/__init__.py`` lists what each such module exports).
+This module holds the pieces that do not depend on the architecture:
 
-The equations are those of the configuration file as it is run: RMSNorm,
-rotate-half RoPE, grouped-query attention with an optional QKV bias, a
-SwiGLU MLP, tied embeddings, and the file's embedding, attention, residual
-and logit multipliers.
+* the subtractor pairing, worked out again by the benchmark's own code
+  from the rule the program implements (structured pairing: one row
+  pairing shared by every column of a weight, ``y = (x[I] - x[J]) K +
+  x[R] W_R`` with ``K = (W[I] - W[J]) / 2``), and the effective weights
+  ``W[I] = +K, W[J] = -K, W[R] = W[R]`` a forward applies in float32;
+* the arithmetic of a forward: ``matmul`` (in fp8 operands for the
+  control: e4m3, per-token and per-channel scales, the precision below the
+  configurations' bfloat16), ``rms_norm`` and rotate-half ``rope``;
+* the teacher-forced reading of a served run: the padded batch of whole
+  sequences, and, a block of positions at a time, how far the logit of the
+  token a server ranked first lies below the reference's best.
 
-It runs layer by layer over a padded batch of whole sequences (causal
-attention, so padding after a sequence never reaches it), so the only
-float32 weights on the device at a time are one layer's.  ``served_gaps``
-reads, at each position the server decoded, how far the logit of the token
-the server ranked first lies below the reference's best; with
-``control=True`` the same forward runs a second time with every linear
-layer in fp8 (e4m3, per-token and per-channel scales), the precision below
-the configuration's bfloat16, and reads the gap of the token that the fp8
-forward ranks first.
+Nothing here imports the program or takes anything the program made.
 """
 from __future__ import annotations
 
@@ -30,11 +25,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights
-
-#: weights the program pairs, as (sub-block, name), in the order they run
-PAIRED = (("attn", "wq"), ("attn", "wk"), ("attn", "wv"), ("attn", "wo"),
-          ("mlp", "w_gate"), ("mlp", "w_up"), ("mlp", "w_down"))
 #: the program leaves a weight unpaired when either GEMM dim is below this
 MIN_DIM = 8
 #: positions whose logits are built at once (bounds the (n, c, vocab) block)
@@ -46,49 +36,6 @@ WALK_CHUNK = 512
 def padded_vocab(vocab: int) -> int:
     """Rows of the embedding table: the vocabulary rounded up to 128."""
     return -(-vocab // 128) * 128
-
-
-def sizes(cfg: dict) -> dict:
-    """The sizes of a configuration file, under short names."""
-    d = cfg["hidden_size"]
-    H = cfg["num_attention_heads"]
-    return {
-        "L": cfg["num_hidden_layers"], "d": d, "H": H,
-        "KH": cfg["num_key_value_heads"], "hd": cfg.get("head_dim", d // H),
-        "f": cfg["intermediate_size"], "V": cfg["vocab_size"],
-        "Vp": padded_vocab(cfg["vocab_size"]),
-    }
-
-
-def layout(cfg: dict) -> dict[str, tuple[int, ...]]:
-    """Path -> shape of every weight, stacked over layers."""
-    s = sizes(cfg)
-    L, d, H, KH, hd, f = s["L"], s["d"], s["H"], s["KH"], s["hd"], s["f"]
-    seg = "segments/0/"
-    out = {
-        "embed": (s["Vp"], d),
-        "final_norm/scale": (d,),
-        seg + "ln1/scale": (L, d),
-        seg + "attn/wq": (L, d, H, hd),
-        seg + "attn/wk": (L, d, KH, hd),
-        seg + "attn/wv": (L, d, KH, hd),
-        seg + "attn/wo": (L, H, hd, d),
-        seg + "ln2/scale": (L, d),
-        seg + "mlp/w_gate": (L, d, f),
-        seg + "mlp/w_up": (L, d, f),
-        seg + "mlp/w_down": (L, f, d),
-    }
-    if cfg["attention_bias"]:
-        out.update({seg + "attn/bq": (L, H, hd), seg + "attn/bk": (L, KH, hd),
-                    seg + "attn/bv": (L, KH, hd)})
-    return out
-
-
-def gemm_view(name: str, w: np.ndarray) -> np.ndarray:
-    """(K, N) GEMM view of one layer's weight."""
-    if name == "wo":
-        return w.reshape(-1, w.shape[-1])
-    return w.reshape(w.shape[0], -1)
 
 
 # ---------------------------------------------------------------------------
@@ -173,7 +120,7 @@ def effective_weight(W, a, b, partner):
 
 
 # ---------------------------------------------------------------------------
-# forward
+# the arithmetic of a forward
 # ---------------------------------------------------------------------------
 
 
@@ -205,112 +152,20 @@ def rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
 
 
-def make_layer(cfg: dict, low: bool):
-    """One decoder layer over a padded batch (n, S, d), jitted."""
-    s = sizes(cfg)
-    H, KH, hd = s["H"], s["KH"], s["hd"]
-    eps = cfg["rms_norm_eps"]
-    att_mult = cfg["attention_multiplier"]
-    res_mult = cfg["residual_multiplier"]
-    theta = cfg["rope_theta"]
-
-    @jax.jit
-    def layer(h, w):
-        n, S, _ = h.shape
-        x = rms_norm(h, w["ln1"], eps)
-        q = matmul(x, w["wq"], low).reshape(n, S, H, hd)
-        k = matmul(x, w["wk"], low).reshape(n, S, KH, hd)
-        v = matmul(x, w["wv"], low).reshape(n, S, KH, hd)
-        if "bq" in w:
-            q, k, v = q + w["bq"], k + w["bk"], v + w["bv"]
-        q, k = rope(q, theta), rope(k, theta)
-        q = q.reshape(n, S, KH, H // KH, hd)  # query head h reads kv head h // G
-        scores = jnp.einsum("nqkgd,nskd->nkgqs", q, k) * att_mult
-        causal = jnp.tril(jnp.ones((S, S), bool))
-        scores = jnp.where(causal, scores, -jnp.inf)
-        attn = jnp.einsum("nkgqs,nskd->nqkgd", jax.nn.softmax(scores, axis=-1), v)
-        h = h + res_mult * matmul(attn.reshape(n, S, H * hd), w["wo"], low)
-        x = rms_norm(h, w["ln2"], eps)
-        g = jax.nn.silu(matmul(x, w["w_gate"], low))
-        return h + res_mult * matmul(g * matmul(x, w["w_up"], low), w["w_down"], low)
-
-    return layer
+# ---------------------------------------------------------------------------
+# the teacher-forced reading of a served run
+# ---------------------------------------------------------------------------
 
 
-def make_head(cfg: dict, low: bool):
-    """Final norm + tied head over a block of positions: (n, c, d) -> (n, c, V)."""
-    eps, V = cfg["rms_norm_eps"], cfg["vocab_size"]
-    scale = 1.0 / cfg["logits_scaling"]
+def teacher_forced(sequences: list, shape: tuple[int, int] | None):
+    """Tokens and targets (n, S) of a padded batch of whole sequences.
 
-    @jax.jit
-    def head(h, norm, embed):
-        hn = rms_norm(h, norm, eps)
-        return matmul(hn, embed[:V].T, low) * scale
-
-    return head
-
-
-def pairings(cfg: dict, params: dict, rounding: float) -> dict:
-    """Row mix of every paired weight of every layer, worked out on the host."""
-    out = {}
-    if rounding <= 0:
-        return out
-    for sub, name in PAIRED:
-        stack = np.asarray(params[f"segments/0/{sub}/{name}"])
-        for l in range(stack.shape[0]):
-            W2 = gemm_view(name, stack[l])
-            if min(W2.shape) >= MIN_DIM:
-                I, J, _ = pair_rows(W2.astype(np.float64), rounding)
-                out[name, l] = row_mix(W2.shape[0], I, J)
-    return out
-
-
-def _layer_weights(params, l, mixes):
-    """Effective float32 weights of layer ``l``."""
-    seg = "segments/0/"
-    w = {"ln1": params[seg + "ln1/scale"][l].astype(jnp.float32),
-         "ln2": params[seg + "ln2/scale"][l].astype(jnp.float32)}
-    for sub, name in PAIRED:
-        W = params[f"{seg}{sub}/{name}"][l]
-        W2 = W.reshape(-1, W.shape[-1]) if name == "wo" else W.reshape(W.shape[0], -1)
-        if (name, l) in mixes:
-            w[name] = effective_weight(W2, *mixes[name, l])
-        else:
-            w[name] = W2.astype(jnp.float32)
-    for b in ("bq", "bk", "bv"):
-        if seg + "attn/" + b in params:
-            w[b] = params[seg + "attn/" + b][l].astype(jnp.float32)
-    return w
-
-
-def served_gaps(cfg: dict, seed: int, rounding: float, sequences: list,
-                *, control: bool = False, params: dict | None = None,
-                shape: tuple[int, int] | None = None) -> dict:
-    """Teacher-forced gaps of the tokens a server ranked first.
-
-    ``sequences``: per request ``(prompt, served, start, picks)``: the
-    prompt, the tokens served, and the token the server ranked first at
-    each position from ``start`` on (a prompt position's pick is what the
-    server would have said there; from the prompt's last position on, the
-    picks are the served tokens).  The reference runs once over prompt +
-    served[:-1]; at each judged position it reads ``max(ref) - ref[pick]``.
+    ``sequences``: per request ``(prompt, served, start, picks)``; the row
+    holds prompt + served[:-1], and the target at each position from
+    ``start`` on is the token the server ranked first there (-1 elsewhere).
     ``shape`` (rows, positions) pads the batch to a fixed size, so that
-    every run of a cell reuses the same compiled programs.  Returns
-    ``{"gaps": [per request array], "seconds": {...}}`` (where the time
-    went), and with ``control`` also ``"control_gaps"``: the same read for
-    the token the fp8 forward ranks first.
+    every run of a cell reuses the same compiled programs.
     """
-    with jax.default_matmul_precision("highest"):
-        return _served_gaps(cfg, seed, rounding, sequences, control, params, shape)
-
-
-def _served_gaps(cfg, seed, rounding, sequences, control, params, shape):
-    import time
-
-    clock = {"start": time.perf_counter()}
-    s = sizes(cfg)
-    if params is None:
-        params = weights.make(seed, layout(cfg), cfg["torch_dtype"])
     n = len(sequences)
     S = max(len(p) + len(t) - 1 for p, t, _, _ in sequences)
     if shape is not None:
@@ -326,60 +181,45 @@ def _served_gaps(cfg, seed, rounding, sequences, control, params, shape):
                 f"request {r}: {len(picks)} picks for positions {start}..{len(seq) - 1}")
         toks[r, :len(seq)] = seq
         targets[r, start:len(seq)] = picks
-    embed = params["embed"].astype(jnp.float32)
-    h = embed[jnp.asarray(toks)] * cfg["embedding_multiplier"]
-    streams = {"ref": h}
-    if control:
-        streams["low"] = h
-    layers = {k: make_layer(cfg, k == "low") for k in streams}
-    clock["weights"] = time.perf_counter()
-    mixes = pairings(cfg, params, rounding)
-    clock["pairing"] = time.perf_counter()
-    for l in range(s["L"]):
-        w = _layer_weights(params, l, mixes)
-        streams = {k: layers[k](v, w) for k, v in streams.items()}
-        del w
-    norm = params["final_norm/scale"].astype(jnp.float32)
-    heads = {k: make_head(cfg, k == "low") for k in streams}
+    return toks, targets
 
+
+def chunked_gaps(targets: np.ndarray, ref_logits, low_logits=None):
+    """(gap, control gap), each (n, S) float64, read a block of positions at a time.
+
+    ``ref_logits(sl)`` gives the reference's logits (n, c, V) at the
+    positions ``sl``; the gap there is how far the target's logit lies
+    below the reference's best.  With ``low_logits``, the control's logits
+    at the same positions, the control gap is that of the token the control
+    ranks first; without, it stays zero.
+    """
+    n, S = targets.shape
     gap = np.zeros((n, S), np.float64)
     gap_low = np.zeros((n, S), np.float64)
     tgt = jnp.asarray(targets)
     for c0 in range(0, S, LOGIT_CHUNK):
         sl = slice(c0, min(S, c0 + LOGIT_CHUNK))
-        ref = heads["ref"](streams["ref"][:, sl], norm, embed)
+        ref = ref_logits(sl)
         best = ref.max(-1)
         t = tgt[:, sl]
         picked = jnp.take_along_axis(ref, jnp.maximum(t, 0)[..., None], -1)[..., 0]
         gap[:, sl] = np.asarray(best - picked)
-        if control:
-            low = heads["low"](streams["low"][:, sl], norm, embed)
-            pick = jnp.argmax(low, axis=-1)
+        if low_logits is not None:
+            pick = jnp.argmax(low_logits(sl), axis=-1)
             low_picked = jnp.take_along_axis(ref, pick[..., None], -1)[..., 0]
             gap_low[:, sl] = np.asarray(best - low_picked)
-    clock["forward"] = time.perf_counter()
-    n = len(sequences)
+    return gap, gap_low
+
+
+def gaps_result(n: int, targets: np.ndarray, gap: np.ndarray, gap_low: np.ndarray | None,
+                clock: dict) -> dict:
+    """``served_gaps``' result for the first ``n`` rows: ``{"gaps": [per
+    request array], "seconds": {...}}`` (where the time went, from the
+    ``clock`` marks ``start``, ``weights``, ``pairing`` and ``forward``),
+    and ``"control_gaps"`` where ``gap_low`` is given."""
     out = {"gaps": [gap[r][targets[r] >= 0] for r in range(n)],
            "seconds": {k: clock[k] - clock[p] for p, k in
                        (("start", "weights"), ("weights", "pairing"), ("pairing", "forward"))}}
-    if control:
+    if gap_low is not None:
         out["control_gaps"] = [gap_low[r][targets[r] >= 0] for r in range(n)]
     return out
-
-
-def logits(cfg: dict, seed: int, rounding: float, tokens: np.ndarray,
-           params: dict | None = None) -> np.ndarray:
-    """Reference logits (n, S, V) of token sequences (n, S), for tests."""
-    with jax.default_matmul_precision("highest"):
-        if params is None:
-            params = weights.make(seed, layout(cfg), cfg["torch_dtype"])
-        s = sizes(cfg)
-        embed = params["embed"].astype(jnp.float32)
-        h = embed[jnp.asarray(tokens)] * cfg["embedding_multiplier"]
-        layer = make_layer(cfg, False)
-        mixes = pairings(cfg, params, rounding)
-        for l in range(s["L"]):
-            h = layer(h, _layer_weights(params, l, mixes))
-        norm = params["final_norm/scale"].astype(jnp.float32)
-        return np.asarray(make_head(cfg, False)(h, norm, embed))
-

@@ -8,7 +8,11 @@ entries, and edits nothing:
 * ``<config file>``: the configuration's entry names it (``bench/configs/``);
 * ``bench/traffic/<mix>.json``: a traffic mix, read by ``bench.traffic``;
 * ``bench/cells/<workload>.json``: a cell's batch, cache length and limits;
-* ``bench/metrics/<metric>.py``: the metric's reader, ``read(run)``.
+* ``bench/metrics/<metric>.py``: the metric's reader, ``read(run)``;
+* ``bench/programs/<architecture>.py`` and ``bench/references/
+  <architecture>.py``: the program adapter and the plain reference of the
+  architecture a configuration file names (``"architecture"``), so a new
+  architecture joins by adding these two files.
 """
 from __future__ import annotations
 
@@ -54,12 +58,39 @@ def metrics(workload_name: str, kind: str, root: Path = ROOT) -> list[dict]:
             if "workloads" not in m or workload_name in m["workloads"]]
 
 
+def _load(path: Path, name: str, missing: str):
+    """The module at ``path``, loaded under ``name``; raises ``missing``
+    when there is no such file."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    if spec is None or not path.exists():
+        raise FileNotFoundError(missing)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _architecture(cfg: dict, kind: str, root: Path):
+    arch = cfg.get("architecture")
+    if arch is None:
+        raise KeyError(f"configuration {cfg.get('name')!r} names no architecture")
+    path = Path(root) / "bench" / kind / f"{arch}.py"
+    return _load(path, f"bench_{kind}_{arch.replace('.', '_').replace('-', '_')}",
+                 f"configuration {cfg.get('name')!r} names architecture {arch!r}, "
+                 f"which has no {kind[:-1]}: looked for {path}")
+
+
+def program(cfg: dict, root: Path = ROOT):
+    """The program adapter ``bench/programs/<architecture>.py`` of a configuration."""
+    return _architecture(cfg, "programs", root)
+
+
+def reference(cfg: dict, root: Path = ROOT):
+    """The plain reference ``bench/references/<architecture>.py`` of a configuration."""
+    return _architecture(cfg, "references", root)
+
+
 def reader(metric: str, root: Path = ROOT):
     """The ``read(run)`` function of ``bench/metrics/<metric>.py``."""
     path = Path(root) / "bench" / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{metric.replace('.', '_')}", path)
-    if spec is None or not path.exists():
-        raise FileNotFoundError(f"no reader for metric {metric!r} at {path}")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(path, f"bench_metric_{metric.replace('.', '_')}",
+                 f"no reader for metric {metric!r} at {path}").read

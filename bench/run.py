@@ -4,11 +4,14 @@
     python3 bench/run.py --workload qwen2-1.5b.decode --seed 7 --seconds 30 --trace 0
 
 The cell (``BENCHMARK.json`` ``workloads``) names a configuration and a
-traffic mix; ``bench/registry.py`` finds their files.  The run:
+traffic mix; ``bench/registry.py`` finds their files, and the program
+adapter (``bench/programs/``) and plain reference (``bench/references/``)
+of the architecture the configuration file names.  The run:
 
 1. exits non-zero, printing no result, without a TPU or with fewer chips
    than the cell asks for;
-2. turns on JAX's persistent compilation cache inside the checkout;
+2. keeps freed host memory in glibc's heap (``keep_host_pages``) and turns
+   on JAX's persistent compilation cache inside the checkout;
 3. makes the weights on the device from ``--seed`` (``bench/weights.py``),
    pairs them and builds one ``ServeEngine`` (the program's own set-up);
 4. drives the engine with the closed-loop client (``bench/driver.py``)
@@ -17,7 +20,7 @@ traffic mix; ``bench/registry.py`` finds their files.  The run:
 5. measures ``--seconds`` of wall clock (with ``--trace 1``, at most
    ``TRACE_SECONDS`` of it, under the profiler);
 6. reads the peak device memory, frees the program, and holds a sample of
-   the finished requests to the plain reference (``bench/reference.py``);
+   the finished requests to the plain reference;
 7. prints the numbers compared beside their limits on stderr, then one JSON
    line on stdout: ``correct``, ``attempted``, ``failed``, ``metrics``,
    ``device`` (and ``breakdown`` with ``--trace 1``), and ``check`` last.
@@ -31,6 +34,8 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import ctypes  # noqa: E402
+import ctypes.util  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -51,6 +56,30 @@ TRACE_SECONDS = 5.0
 SCRATCH = ROOT / ".cache" / "bench"
 
 
+#: glibc ``mallopt`` parameters
+M_TRIM_THRESHOLD, M_MMAP_MAX = -1, -4
+
+
+def keep_host_pages() -> bool:
+    """Have glibc serve this process's large allocations from its heap and
+    keep what is freed there.
+
+    A decode step copies its logits (64 x 151,936 float32, 38.9 MB, above
+    glibc's 32 MB ceiling for heap chunks) into a new host buffer.  Left to
+    the default, each step maps fresh pages and faults them in, at a cost
+    that turns on how many huge pages the kernel grants the mapping; the
+    copy's time on a v5e host differs from one process to the next (5-16 ms
+    a step) and the end-to-end metrics with it.  Kept in the heap, each
+    step reuses resident pages.  Returns False where the C library has no
+    ``mallopt``."""
+    try:
+        libc = ctypes.CDLL(ctypes.util.find_library("c"))
+        return (libc.mallopt(M_MMAP_MAX, 0) == 1
+                and libc.mallopt(M_TRIM_THRESHOLD, 2**31 - 1) == 1)
+    except (OSError, AttributeError):
+        return False
+
+
 def log(msg: str) -> None:
     print(f"[bench] {msg}", file=sys.stderr, flush=True)
 
@@ -66,74 +95,35 @@ def check_devices(chips: int, require_tpu: bool):
     return devs[:chips]
 
 
-def program_config(cfg: dict):
-    """The program's ModelConfig for a configuration file, which states every
-    size; raises where the file asks for equations the program cannot run."""
-    import dataclasses
-    import math
+def program_config(cfg: dict, root: Path = ROOT):
+    """The program's ModelConfig for a configuration file, from the adapter
+    of the architecture the file names."""
+    from bench import registry
 
-    from repro.configs import get_config
-
-    base = get_config(cfg["program_config"])
-    pcfg = dataclasses.replace(
-        base,
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"], n_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        rope_theta=cfg["rope_theta"], qkv_bias=cfg["attention_bias"],
-        tie_embeddings=cfg["tie_word_embeddings"], dtype=cfg["torch_dtype"],
-    )
-    fixed = {
-        "head_dim": pcfg.head_dim,
-        "rms_norm_eps": 1e-6,
-        "hidden_act": pcfg.act,
-        "embedding_multiplier": math.sqrt(pcfg.d_model) if pcfg.tie_embeddings else 1.0,
-        "attention_multiplier": 1.0 / math.sqrt(pcfg.head_dim),
-        "residual_multiplier": 1.0,
-        "logits_scaling": 1.0,
-    }
-    for key, want in fixed.items():
-        got = cfg[key]
-        same = got == want if isinstance(want, str) else math.isclose(got, want, rel_tol=1e-9)
-        if not same:
-            raise ValueError(f"{cfg['name']}: {key}={got!r}, the program runs {want!r}")
-    if pcfg.family != "dense" or pcfg.mla or pcfg.moe or pcfg.qk_norm or pcfg.sliding_window:
-        raise ValueError(f"{cfg['name']}: only the dense GQA decoder is supported")
-    return pcfg
+    return registry.program(cfg, root).program_config(cfg)
 
 
-def build_params(pcfg, cfg: dict, seed: int):
-    """Seeded weights in the program's tree, after checking that tree is the
-    one the reference defines."""
+def build_params(pcfg, cfg: dict, seed: int, root: Path = ROOT):
+    """Seeded weights in the program's tree, after checking that the tree
+    holds exactly the leaves the architecture's reference draws."""
+    from bench import registry
+
+    params = registry.program(cfg, root).params(pcfg, cfg, seed)
+    check_layout(params, registry.reference(cfg, root).layout(cfg))
+    return params
+
+
+def check_layout(params, want: dict) -> None:
+    """Raises unless the program's tree holds exactly the leaves of ``want``
+    (path -> shape)."""
     import jax
 
-    from bench import reference, weights
-    from repro.models import lm as M
-    from repro.models.param import unzip
+    from bench import weights
 
-    shapes = jax.eval_shape(lambda: unzip(M.init_lm(pcfg, jax.random.key(0)))[0])
-    flat, _ = jax.tree_util.tree_flatten_with_path(shapes)
-    got = {weights.path_str(p): tuple(s.shape) for p, s in flat}
-    want = reference.layout(cfg)
+    flat, _ = jax.tree_util.tree_flatten_with_path(params)
+    got = {weights.path_str(p): tuple(x.shape) for p, x in flat}
     if got != want:
         raise ValueError(f"program parameters {got} differ from the reference layout {want}")
-    return weights.make_tree(seed, shapes, cfg["torch_dtype"])
-
-
-def pair_counts(params) -> dict:
-    """Per-layer pair and residual lane counts of each paired weight."""
-    import numpy as np
-
-    seg = params["segments"][0]
-    out = {}
-    for sub in ("attn", "mlp"):
-        for k, meta in seg.get(sub, {}).items():
-            if k.endswith("_pairing"):
-                out[k[:-len("_pairing")]] = (
-                    np.asarray(meta["pair_mask"]).sum(axis=1).astype(int),
-                    np.asarray(meta["resid_mask"]).sum(axis=1).astype(int),
-                )
-    return out
 
 
 def sample_for_check(completed: list, seed: int, n: int) -> list:
@@ -182,23 +172,24 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     cfg = registry.config(wl["config"], root)
     mix = registry.traffic(wl["traffic"], root)
     cell = registry.cell(workload, root)
+    program, reference = registry.program(cfg, root), registry.reference(cfg, root)
     devs = check_devices(wl["chips"], require_tpu)
 
     import jax
     import numpy as np
 
-    from bench import driver, reference, traffic
+    from bench import driver, traffic
     from repro.models import lm as M
     from repro.serving.engine import ServeEngine
 
     dev = devs[0]
     log(f"device {dev.platform} {dev.device_kind} x{len(devs)}; cell {workload}, seed {seed}")
     traffic.validate(mix, cell["max_seq"])
-    pcfg = program_config(cfg)
+    pcfg = program_config(cfg, root)
     dep = cfg["deployment"]
 
     t = time.perf_counter()
-    params = build_params(pcfg, cfg, seed)
+    params = build_params(pcfg, cfg, seed, root)
     jax.block_until_ready(params)
     log(f"weights made in {time.perf_counter() - t:.1f}s")
     knobs = M.PerfKnobs(q_chunk=dep["q_chunk"], k_chunk=dep["k_chunk"], remat="none",
@@ -208,7 +199,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     eng = ServeEngine(pcfg, params, max_seq=cell["max_seq"], batch_size=cell["batch_slots"],
                       knobs=knobs)
     log(f"engine built (pairing included) in {time.perf_counter() - t:.1f}s")
-    pairs = pair_counts(eng.params)
+    pairs = program.pair_counts(eng.params)
     if fault is not None:
         fault(eng)
 
@@ -257,7 +248,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         workload=workload, cell=cell, config=cfg, mix=mix, setup_s=setup_s,
         t0=t0, t1=t1, steps=steps, admits=admits, served=loop.served,
         completed=completed, pairs=pairs, device_kind=dev.device_kind,
-        n_chips=len(devs), batch_slots=cell["batch_slots"], trace=None)
+        n_chips=len(devs), batch_slots=cell["batch_slots"], trace=None,
+        reference=reference)
 
     # free the program before the reference runs
     sample = sample_for_check(completed, seed, cell["check_requests"])
@@ -326,6 +318,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     # the TPU runtime logs inside the checkout, not to a fixed path in /tmp
     os.environ.setdefault("TPU_LOG_DIR", str(SCRATCH / "tpu_logs"))
+    log(f"host pages kept: {keep_host_pages()}")
     import jax
 
     from repro.launch.compile_cache import enable_compile_cache

@@ -1,9 +1,11 @@
 """The harness and the traffic generator on the CPU: seeded traffic, the
-files found by name, the benchmark's own file, and whole runs of a tiny
-cell (``bench/tests/tiny.py``) through the timed path."""
+files found by name (an architecture's program adapter and reference
+among them), the benchmark's own file, and whole runs of a tiny cell
+(``bench/tests/tiny.py``) through the timed path."""
 import json
 import math
 import re
+import shutil
 import subprocess
 import sys
 from pathlib import Path
@@ -18,6 +20,8 @@ REPO = Path(__file__).resolve().parents[2]
 BENCH = registry.benchmark()
 MIXES = sorted(p.stem for p in (REPO / "bench" / "traffic").glob("*.json"))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+ARCHS = sorted(p.stem for p in (REPO / "bench" / "references").glob("*.py")
+               if p.stem != "__init__")
 
 
 def take(mix, seed, n, vocab=1000):
@@ -124,6 +128,90 @@ def test_new_files_are_found_by_name(tmp_path):
     assert after == before
 
 
+def source_files(d: Path) -> dict:
+    return {p: p.read_bytes() for p in d.rglob("*")
+            if p.is_file() and "__pycache__" not in p.parts}
+
+
+def test_architecture_modules_are_found_by_name():
+    cfg = registry.config("qwen2-1.5b")
+    assert cfg["architecture"] == "dense_gqa"
+    program, ref = registry.program(cfg), registry.reference(cfg)
+    assert Path(program.__file__) == REPO / "bench" / "programs" / "dense_gqa.py"
+    assert Path(ref.__file__) == REPO / "bench" / "references" / "dense_gqa.py"
+    for name in ("program_config", "params", "pair_counts"):
+        assert callable(getattr(program, name))
+    for name in ("layout", "served_gaps", "logits", "gemms", "flops_per_position",
+                 "attention_work", "head_flops"):
+        assert callable(getattr(ref, name))
+    assert program.program_config(cfg).n_layers == 28
+    assert ref.gemms(cfg)["w_down"] == (8960, 1536, True)
+
+
+@pytest.mark.parametrize("kind", ["program", "reference"])
+def test_an_unknown_architecture_names_the_file_it_looked_for(kind):
+    cfg = {"name": "x", "architecture": "no_such_arch"}
+    want = REPO / "bench" / f"{kind}s" / "no_such_arch.py"
+    with pytest.raises(FileNotFoundError, match=re.escape(str(want))):
+        getattr(registry, kind)(cfg)
+    with pytest.raises(KeyError, match="names no architecture"):
+        getattr(registry, kind)({"name": "x"})
+
+
+def test_an_unknown_architecture_fails_before_any_device_work(tmp_path):
+    """On the CPU with ``require_tpu``, the look for a chip would end the
+    run; the missing adapter is found first."""
+    root = tiny.make_root(tmp_path, config={**tiny.CONFIG, "architecture": "no_such_arch"})
+    with pytest.raises(FileNotFoundError, match=re.escape("programs/no_such_arch.py")):
+        run.run_cell("tiny.decode", 1, 1.0, False, root=root, require_tpu=True)
+
+
+@pytest.mark.parametrize("arch", ARCHS)
+def test_the_program_holds_the_references_layout(arch):
+    """Each architecture's adapter, at its tiny size, builds a tree with
+    exactly the leaves its reference draws (``tiny_configs/<arch>.json``)."""
+    import jax
+
+    cfg = tiny.tiny_config(arch)
+    program, ref = registry.program(cfg), registry.reference(cfg)
+    params = program.params(program.program_config(cfg), cfg, 3)
+    flat, _ = jax.tree_util.tree_flatten_with_path(params)
+    assert {weights.path_str(p): tuple(x.shape) for p, x in flat} == ref.layout(cfg)
+    run.build_params(run.program_config(cfg), cfg, 3)  # checks the same, raising
+    short = dict(ref.layout(cfg))
+    short.popitem()
+    with pytest.raises(ValueError, match="differ from the reference layout"):
+        run.check_layout(params, short)
+
+
+def test_an_architecture_joins_by_files_alone(tmp_path):
+    """A second architecture, added to a checkout root as a program adapter
+    and a reference (copies of dense GQA's under another name), a
+    configuration, a cell and entries in BENCHMARK.json, runs correct, and
+    no file of bench/ changes.  The originals leave the root, so the run
+    can only have used the copies."""
+    before = source_files(REPO / "bench")
+    root = tiny.make_root(tmp_path)
+    for kind in ("programs", "references"):
+        d = root / "bench" / kind
+        shutil.copy(d / "dense_gqa.py", d / "twin.py")
+        (d / "dense_gqa.py").unlink()
+    cfg = {**tiny.CONFIG, "name": "twin", "architecture": "twin"}
+    (root / "bench" / "configs" / "twin.json").write_text(json.dumps(cfg))
+    (root / "bench" / "cells" / "twin.decode.json").write_text(json.dumps(tiny.CELL))
+    b = json.loads((root / "BENCHMARK.json").read_text())
+    b["configs"].append({"name": "twin", "source": cfg["source"],
+                         "file": "bench/configs/twin.json", "reduced": [], "why": "CPU test"})
+    b["workloads"].append({"name": "twin.decode", "config": "twin", "traffic": "tiny",
+                           "chips": 1, "why": "CPU test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(b))
+    assert Path(registry.reference(cfg, root).__file__) == root / "bench/references/twin.py"
+    r = run.run_cell("twin.decode", 2**33 + 9, 1.5, False, root=root, require_tpu=False)
+    assert r["correct"] and r["failed"] == 0 and r["attempted"] > 0
+    assert r["check"]["max_logit_gap"]["value"] <= r["check"]["max_logit_gap"]["limit"]
+    assert source_files(REPO / "bench") == before
+
+
 def test_benchmark_file_keeps_to_its_contract():
     assert BENCH["command"] == ["python3", "bench/run.py"] and BENCH["paths"] == ["bench"]
     assert isinstance(BENCH["run_seconds"], int) and 1 <= BENCH["run_seconds"] <= 51
@@ -133,6 +221,9 @@ def test_benchmark_file_keeps_to_its_contract():
     for c in BENCH["configs"]:
         assert NAME.match(c["name"]) and (REPO / c["file"]).is_file()
         assert c["reduced"] == registry.config(c["name"])["reduced"]
+        arch = registry.config(c["name"])["architecture"]
+        for kind in ("programs", "references"):
+            assert (REPO / "bench" / kind / f"{arch}.py").is_file()
     for w in BENCH["workloads"]:
         assert NAME.match(w["name"]) and w["chips"] in (1, 4) and len(w["why"]) <= 200
         assert (REPO / "bench" / "traffic" / f"{w['traffic']}.json").is_file()
@@ -174,3 +265,34 @@ def test_without_a_tpu_the_run_prints_no_result():
         env={"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin"})
     assert p.returncode != 0 and p.stdout.strip() == ""
     assert "needs a TPU" in p.stderr
+
+
+MAPPED_BYTES = """
+import ctypes, sys
+sys.path.insert(0, {root!r})
+import numpy as np
+from bench import run
+
+class Info(ctypes.Structure):
+    _fields_ = [(n, ctypes.c_size_t) for n in ("arena", "ordblks", "smblks", "hblks", "hblkhd",
+                "usmblks", "fsmblks", "uordblks", "fordblks", "keepcost")]
+
+libc = ctypes.CDLL(None)
+libc.mallinfo2.restype = Info
+kept = run.keep_host_pages() if {keep} else False
+before = libc.mallinfo2().hblkhd
+logits = np.ones((64, 151936), np.float32)
+print(kept, libc.mallinfo2().hblkhd - before)
+"""
+
+
+@pytest.mark.parametrize("keep", [False, True])
+def test_kept_host_pages_take_the_logits_buffer_from_the_heap(keep):
+    """With ``keep_host_pages`` a step's 38.9 MB logits buffer comes from
+    glibc's heap, not from a mapping of its own; without it, it is mapped."""
+    p = subprocess.run([sys.executable, "-c", MAPPED_BYTES.format(root=str(REPO), keep=keep)],
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stderr
+    kept, mapped = p.stdout.split()
+    assert kept == str(keep)
+    assert (int(mapped) == 0) if keep else (int(mapped) >= 64 * 151936 * 4)

@@ -1,5 +1,7 @@
-"""The plain reference against the program's own forward (``models.lm``) on
-the CPU, at the smoke sizes of both configurations, with and without pairs.
+"""The plain dense GQA reference (``bench/references/dense_gqa.py``, with
+the shared pair walk of ``bench/reference.py``) against the program's own
+forward (``models.lm``) on the CPU, at the smoke sizes of both
+configurations, with and without pairs.
 
 Both run in float32 at full matmul precision on the same seeded weights, so
 they agree to float32 rounding over the depth; a rolled pair permutation or
@@ -15,23 +17,12 @@ import numpy as np
 import pytest
 
 from bench import reference, weights
+from bench.references import dense_gqa
+from bench.tests.tiny import bench_config
 
 #: max |reference - program| / max |program| over all logits: float32 at
 #: full precision on both sides, two layers
 TOL = 1e-4
-
-
-def bench_config(pcfg) -> dict:
-    return {
-        "name": pcfg.name, "hidden_size": pcfg.d_model, "intermediate_size": pcfg.d_ff,
-        "num_hidden_layers": pcfg.n_layers, "num_attention_heads": pcfg.n_heads,
-        "num_key_value_heads": pcfg.n_kv_heads, "head_dim": pcfg.head_dim,
-        "vocab_size": pcfg.vocab, "rope_theta": pcfg.rope_theta, "rms_norm_eps": 1e-6,
-        "attention_bias": pcfg.qkv_bias, "tie_word_embeddings": pcfg.tie_embeddings,
-        "torch_dtype": "float32", "embedding_multiplier": math.sqrt(pcfg.d_model),
-        "attention_multiplier": 1 / math.sqrt(pcfg.head_dim),
-        "residual_multiplier": 1.0, "logits_scaling": 1.0,
-    }
 
 
 def program_logits(pcfg, params, rounding, tokens):
@@ -70,7 +61,7 @@ def test_reference_matches_program_forward(smoke, rounding):
     want, pairs = program_logits(pcfg, params, rounding, tokens)
     if rounding == 0.3:
         assert pairs > 0, "the check at 0.3 must go through pair lanes"
-    got = reference.logits(cfg, 7, rounding, tokens)
+    got = dense_gqa.logits(cfg, 7, rounding, tokens)
     assert rel_err(got, want) < TOL
 
 
@@ -86,13 +77,13 @@ def test_reference_check_catches_a_broken_forward(smoke, mutation, monkeypatch):
             return np.roll(I, 1), J, R
         monkeypatch.setattr(reference, "pair_rows", rolled)
     else:
-        layer = reference.make_layer
+        layer = dense_gqa.make_layer
 
         def no_skip(cfg, low):
             f = layer(cfg, low)
             return lambda h, w: f(h, w) - h
-        monkeypatch.setattr(reference, "make_layer", no_skip)
-    got = reference.logits(cfg, 7, 0.3, tokens)
+        monkeypatch.setattr(dense_gqa, "make_layer", no_skip)
+    got = dense_gqa.logits(cfg, 7, 0.3, tokens)
     assert rel_err(got, want) > 100 * TOL
 
 

@@ -1,8 +1,18 @@
-"""Operations and bytes (``bench.work``) and the table of peaks."""
+"""Operations and bytes (``bench.work`` and each reference's counts), the
+table of peaks, and the readers that count work, pinned on a trace recorded
+on a TPU v5e."""
+import types
+from pathlib import Path
+
 import numpy as np
 import pytest
 
-from bench import peaks, reference, registry, work
+from bench import peaks, registry, trace_reduce, work
+from bench.driver import Admit, Step
+from bench.references import dense_gqa
+from bench.tests.tiny import bench_config
+
+DATA = Path(__file__).resolve().parent / "data"
 
 
 def test_paired_gemm_work_from_a_known_pairing():
@@ -33,21 +43,22 @@ def test_dense_flops_per_token(arch, want):
     from repro.configs import get_config
 
     c = get_config(arch)
-    got = work.dense_flops_per_position(c.n_layers, c.d_model, c.n_heads, c.n_kv_heads,
-                                        c.head_dim, c.d_ff)
-    assert got == want
-    assert work.head_flops(c.d_model, c.vocab) == 2 * c.d_model * c.vocab
+    cfg = bench_config(c)
+    assert dense_gqa.flops_per_position(cfg) == want
+    assert dense_gqa.head_flops(cfg) == 2 * c.d_model * c.vocab
 
 
 def test_sizes_of_the_benchmark_config():
-    s = reference.sizes(registry.config("qwen2-1.5b"))
+    cfg = registry.config("qwen2-1.5b")
+    s = registry.reference(cfg).sizes(cfg)
     assert (s["L"], s["d"], s["H"], s["KH"], s["hd"], s["f"], s["V"], s["Vp"]) == (
         28, 1536, 12, 2, 128, 8960, 151936, 151936)
 
 
 def test_decode_attention_counts_live_positions_only():
-    a = work.decode_attention(100, 4, 12, 2, 128, 1536, 700, 136)
-    b = work.decode_attention(200, 4, 12, 2, 128, 1536, 700, 136)
+    cfg = registry.config("qwen2-1.5b")
+    a, b = (work.fused_out_projection(dense_gqa.attention_work(cfg, context, 4), 4,
+                                      1536, 1536, 700, 136) for context in (100, 200))
     assert b.flops - a.flops == 4 * 12 * 128 * 100
     assert b.bytes - a.bytes == 2 * 2 * 128 * 100 * 2
 
@@ -62,3 +73,27 @@ def test_least_time_is_the_slower_bound():
     p = peaks.peaks_for("TPU v5 lite")
     assert peaks.least_time_s(197e12, 1.0, p) == pytest.approx(1.0)
     assert peaks.least_time_s(1.0, 819e9, p) == pytest.approx(1.0)
+
+
+#: what the readers read on the recorded trace at the commit before the
+#: work counts moved beside each architecture's reference
+PINNED = {"step_mfu": 0.024767318546192893,
+          "paired_matmul_roofline": 47.96436433255523,
+          "fused_attn_decode_roofline": 17.225416846441828}
+
+
+@pytest.mark.parametrize("metric", sorted(PINNED))
+def test_work_readers_read_the_same_work_on_the_recorded_trace(metric):
+    """``data/v5e_tiny_serve.xplane.pb`` (``test_program_trace.py``): qwen2's
+    widths with 2 layers, 8 slots; one 16-token prefill and four decode
+    steps, with fixed pair counts and contexts."""
+    cfg = dict(registry.config("qwen2-1.5b"), num_hidden_layers=2)
+    names = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+    pairs = {n: (np.array([700 + 10 * l + i for l in range(2)]), np.array([136, 137]))
+             for i, n in enumerate(names)}
+    run = types.SimpleNamespace(
+        config=cfg, reference=registry.reference(cfg), pairs=pairs, batch_slots=8,
+        trace=trace_reduce.summarize(DATA / "v5e_tiny_serve.xplane.pb"),
+        steps=[Step(0.1 * k, 0.1 * k + 0.05, 8, 8 * (17 + k), 8) for k in range(4)],
+        admits=[Admit(0.0, 0.02, 16)], device_kind="TPU v5 lite", n_chips=1, t0=0.0, t1=0.5)
+    assert registry.reader(metric)(run) == pytest.approx(PINNED[metric], rel=1e-12)

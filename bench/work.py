@@ -1,4 +1,7 @@
-"""Operations and bytes the served model needs, computed from shapes.
+"""Operations and bytes the served model needs, computed from shapes: the
+pieces that do not depend on the architecture.  Each architecture's
+reference (``bench/references/<architecture>.py``) counts its own GEMM
+shapes, positions, attention and head from its configuration file.
 
 The counts are of the work the algorithm needs, not of what an
 implementation happens to do: a kernel that streams padding, or rereads
@@ -36,34 +39,14 @@ def paired_gemm(M: int, N: int, P: int, R: int, *, residual: bool = False,
     return Work(flops, read + M * N * ACT_BYTES)
 
 
-def decode_attention(context: int, rows: int, H: int, KH: int, hd: int,
-                     d_out: int, P: int, R: int) -> Work:
-    """One fused decode-attention call with its paired out-projection.
+def fused_out_projection(attn: Work, rows: int, K: int, N: int, P: int, R: int) -> Work:
+    """One layer's attention (``attn``, from the architecture's reference)
+    with its paired out-projection fused into the same kernel.
 
-    ``context``: keys attended, summed over the ``rows`` slots (the live
-    positions only).  Attention reads each live key and value once; the
-    out-projection is a paired GEMM over the H*hd attended lanes with the
-    sublayer residual fused.
+    The out-projection is a paired GEMM of ``rows`` rows over the K
+    attended lanes into N columns, P pairs and R residual rows, with the
+    sublayer residual fused; the attended values never leave the kernel, so
+    their write and reread are not counted.
     """
-    attn = Work(4.0 * H * hd * context,
-                (2 * KH * hd * context + rows * H * hd) * ACT_BYTES)
-    proj = paired_gemm(rows, d_out, P, R, residual=True)
-    # the attended values never leave the kernel: no write and reread of them
-    return attn + Work(proj.flops, proj.bytes - rows * H * hd * ACT_BYTES)
-
-
-def dense_flops_per_position(L: int, d: int, H: int, KH: int, hd: int, f: int) -> float:
-    """Matmul operations of one token position through the decoder stack,
-    as a dense model computes them (pairing changes how, not how much)."""
-    per_layer = 2.0 * (d * H * hd + 2 * d * KH * hd + H * hd * d + 3 * d * f)
-    return L * per_layer
-
-
-def attention_flops(L: int, H: int, hd: int, context: int) -> float:
-    """Score and value products over ``context`` attended keys, all layers."""
-    return 4.0 * L * H * hd * context
-
-
-def head_flops(d: int, V: int) -> float:
-    """The tied output head for one position."""
-    return 2.0 * d * V
+    proj = paired_gemm(rows, N, P, R, residual=True)
+    return attn + Work(proj.flops, proj.bytes - rows * K * ACT_BYTES)
